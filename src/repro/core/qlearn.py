@@ -33,6 +33,7 @@ import numpy as np
 
 from repro.core.modes import CoherenceMode, N_MODES
 from repro.core.state import N_STATES
+from repro.core.vops import iota
 
 # numpy so it inlines as a literal under Pallas tracing
 _NEG = np.float32(-3.4e38)
@@ -218,7 +219,7 @@ def row_update(row, alpha, action, reward):
     ok = jnp.isfinite(reward)
     alpha = jnp.where(ok, alpha, 0.0)
     reward = jnp.where(ok, reward, 0.0)
-    hot = jnp.arange(row.shape[-1], dtype=jnp.int32) == action
+    hot = iota(row.shape[-1]) == action
     return jnp.where(hot, (1.0 - alpha) * row + alpha * reward, row)
 
 

@@ -26,6 +26,8 @@ from typing import NamedTuple
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core.vops import iota, put_col, take_col
+
 # numpy so they inline as literals under Pallas tracing
 _BIG = np.float32(3.4e38)
 _EPS = np.float32(1e-12)
@@ -87,7 +89,7 @@ def _is_min_row():
     # Rows 0..2 track minima, row 3 (mem_max) tracks a maximum.  Built from
     # an iota so tracing embeds no array constant (Pallas kernel bodies
     # reject captured device-array constants).
-    return jnp.arange(4, dtype=jnp.int32) != 3
+    return iota(4) != 3
 
 
 def init_reward_state(n_accs: int) -> RewardState:
@@ -132,7 +134,7 @@ def evaluate(
 
     # Update extrema *including* this invocation (min_{j <= i} in the paper):
     # one column gather, a fused min/max blend, one column write-back.
-    col = state.extrema[:, acc_id]
+    col = take_col(state.extrema, acc_id)
     vals = jnp.stack([exec_s, comm_s, mem_s, mem_s])
     new_col = jnp.where(_is_min_row(), jnp.minimum(col, vals),
                         jnp.maximum(col, vals))
@@ -157,5 +159,5 @@ def evaluate(
     )
 
     reward = weights.x * r_exec + weights.y * r_comm + weights.z * r_mem
-    new_state = RewardState(extrema=state.extrema.at[:, acc_id].set(new_col))
+    new_state = RewardState(extrema=put_col(state.extrema, acc_id, new_col))
     return reward, new_state, (r_exec, r_comm, r_mem)

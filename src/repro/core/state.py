@@ -120,7 +120,7 @@ def observe(
     # Per needed tile: how many active non-coherent accelerators touch it.
     non_coh_mask = active & (active_modes == int(CoherenceMode.NON_COH_DMA))
     per_tile_non_coh = jnp.sum(
-        needed_tiles.astype(jnp.int32) * non_coh_mask[:, None].astype(jnp.int32),
+        needed_tiles.astype(jnp.int32) * non_coh_mask.astype(jnp.int32)[:, None],
         axis=0,
     )
     avg_non_coh = (
@@ -131,7 +131,7 @@ def observe(
     # slice (all modes except non-coherent DMA).
     llc_mask = active & (active_modes != int(CoherenceMode.NON_COH_DMA))
     per_tile_llc = jnp.sum(
-        needed_tiles.astype(jnp.int32) * llc_mask[:, None].astype(jnp.int32),
+        needed_tiles.astype(jnp.int32) * llc_mask.astype(jnp.int32)[:, None],
         axis=0,
     )
     avg_llc = jnp.sum(jnp.where(target_tiles, per_tile_llc, 0)) / n_target_tiles

@@ -20,7 +20,6 @@ from typing import Callable
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 
 def pipeline_apply(
@@ -39,10 +38,10 @@ def pipeline_apply(
         lambda l: P(axis_name, *([None] * (l.ndim - 1))), stage_params)
 
     @functools.partial(
-        shard_map, mesh=mesh,
+        jax.shard_map, mesh=mesh,
         in_specs=(param_specs, P()),       # microbatches replicated
         out_specs=P(),
-        check_rep=False)
+        check_vma=False)
     def run(params, mbs):
         params = jax.tree_util.tree_map(lambda l: l[0], params)
         idx = jax.lax.axis_index(axis_name)

@@ -10,11 +10,13 @@ the updated state back, and emits one packed trace row; the final
 Q-table is written on the last grid step.
 
 Compared to the ``lax.scan`` lowering, every per-step quantity the step
-needs arrives as a ``(1, ...)`` block of one packed float input row and
-one packed int input row (:func:`repro.kernels.soc_step.ref.pack_inputs`
-owns the layout), so observe's per-tile masked reductions and the Q-row
-gather/blend/write-back run over VMEM-resident state with no HBM round
-trip per step.
+needs arrives as one row of a packed float input (VMEM) and one row of a
+packed int input (SMEM, where the scalar unit reads indices and flags);
+:func:`repro.kernels.soc_step.ref.pack_inputs` owns the layout.  So
+observe's per-tile masked reductions and the Q-row select/blend/write-back
+run over VMEM-resident state with no HBM round trip per step.  The TPU
+lowering has no value gather or scatter, so the shared step indexes by
+iota-compare selects (:mod:`repro.core.vops`).
 
 ``interpret=True`` executes the body with the Pallas interpreter — the
 CPU test path.
@@ -42,6 +44,37 @@ N_CONSTS = N_STATIC + 4
 # serving consts: the episode consts plus the ServeParams scalars.
 N_SERVE_CONSTS = N_CONSTS + len(ServeParams._fields)
 
+# Per-step rows (the packed inputs and the trace) travel in ROWS-row
+# blocks: a TPU block's second-to-last dimension must be a multiple of 8
+# or the whole array.  Grid step i works on row i % ROWS of block
+# i // ROWS; consecutive steps share a block, so it is fetched and written
+# back once per ROWS steps.  The last block may be partial.
+ROWS = 8
+
+
+def _rows(width: int, memory_space=None) -> pl.BlockSpec:
+    return pl.BlockSpec((ROWS, width), lambda i: (i // ROWS, 0),
+                        memory_space=memory_space)
+
+
+def _whole(shape) -> pl.BlockSpec:
+    return pl.BlockSpec(shape, lambda i: (0,) * len(shape))
+
+
+def _consts(consts):
+    """Unpack the (1, n) SMEM consts row into (SoCStatic, learned, reward
+    weights, the trailing scalars)."""
+    c = [consts[0, j] for j in range(consts.shape[1])]
+    weights = rewards.RewardWeights(*c[N_STATIC + 1:N_CONSTS])
+    return (SoCStatic(*c[:N_STATIC]), c[N_STATIC] != 0.0, weights,
+            c[N_CONSTS:])
+
+
+def _row_inputs(xf, xi, r, **kw):
+    """Row ``r`` of the current float (VMEM) and int (SMEM) row blocks."""
+    return unpack_inputs(xf[pl.ds(r, 1), :][0],
+                         [xi[r, k] for k in range(xi.shape[1])], **kw)
+
 
 def _episode_kernel(*refs, n_steps: int, n_tiles: int, n_threads: int,
                     n_actions: int, ddr_attribution: bool, gated: bool,
@@ -65,16 +98,11 @@ def _episode_kernel(*refs, n_steps: int, n_tiles: int, n_threads: int,
         if wp is not None:
             wp[...] = wp0[...]
 
-    c = consts[...]
-    s = SoCStatic(*[c[j] for j in range(N_STATIC)])
-    learned = c[N_STATIC] != 0.0
-    weights = rewards.RewardWeights(
-        x=c[N_STATIC + 1], y=c[N_STATIC + 2], z=c[N_STATIC + 3])
+    s, learned, weights, extra = _consts(consts)
     geom, warm_cap = derive_geom(s)
-
-    x = unpack_inputs(xf[...][0], xi[...][0], n_tiles=n_tiles,
-                      n_threads=n_threads, n_actions=n_actions,
-                      faulted=faulted)
+    r = i % ROWS
+    x = _row_inputs(xf, xi, r, n_tiles=n_tiles, n_threads=n_threads,
+                    n_actions=n_actions, faulted=faulted)
 
     if mlp_dims is None:
         qtable_new, rs_new, tbl_new, y = fused_step(
@@ -83,8 +111,8 @@ def _episode_kernel(*refs, n_steps: int, n_tiles: int, n_threads: int,
             ddr_attribution=ddr_attribution, gated=gated)
         wp_new = None
     else:
-        qfun = c[N_CONSTS] != 0.0
-        mlp_lr = c[N_CONSTS + 1]
+        qfun = extra[0] != 0.0
+        mlp_lr = extra[1]
         qtable_new, rs_new, tbl_new, wp_new, y = fused_step(
             s, geom, warm_cap, learned, weights, qt[...],
             rewards.RewardState(extrema=ex[...]), tbl[...], x,
@@ -96,7 +124,7 @@ def _episode_kernel(*refs, n_steps: int, n_tiles: int, n_threads: int,
     qt[...] = qtable_new
     ex[...] = rs_new.extrema
     tbl[...] = tbl_new
-    y_out[...] = y[None, :]
+    y_out[pl.ds(r, 1), :] = y[None, :]
 
     @pl.when(i == n_steps - 1)
     def _finish():
@@ -135,17 +163,14 @@ def soc_step_episode(xf, xi, consts, qtable0, extrema0, wpack0=None, *,
     n_i = xi.shape[1]
     n_states, _ = qtable0.shape
     n_accs = extrema0.shape[1]
-    n_consts = consts.shape[0]
-
-    row = lambda width: pl.BlockSpec((1, width), lambda i: (i, 0))
-    full = lambda shape: pl.BlockSpec(shape, lambda i: (0,) * len(shape))
 
     in_specs = [
-        row(n_f), row(n_i), full((n_consts,)),
-        full((n_states, n_actions)), full((4, n_accs)),
+        _rows(n_f), _rows(n_i, pltpu.SMEM),
+        pl.BlockSpec(memory_space=pltpu.SMEM),
+        _whole((n_states, n_actions)), _whole((4, n_accs)),
     ]
-    operands = [xf, xi, consts, qtable0, extrema0]
-    out_specs = [row(len(YCOLS)), full((n_states, n_actions))]
+    operands = [xf, xi, consts[None, :], qtable0, extrema0]
+    out_specs = [_rows(len(YCOLS)), _whole((n_states, n_actions))]
     out_shape = [
         jax.ShapeDtypeStruct((n_steps, len(YCOLS)), jnp.float32),
         jax.ShapeDtypeStruct((n_states, n_actions), jnp.float32),
@@ -157,9 +182,9 @@ def soc_step_episode(xf, xi, consts, qtable0, extrema0, wpack0=None, *,
     ]
     if mlp_dims is not None:
         wshape = wpack0.shape
-        in_specs.append(full(wshape))
+        in_specs.append(_whole(wshape))
         operands.append(wpack0.astype(jnp.float32))
-        out_specs.append(full(wshape))
+        out_specs.append(_whole(wshape))
         out_shape.append(jax.ShapeDtypeStruct(wshape, jnp.float32))
         scratch_shapes.append(pltpu.VMEM(wshape, jnp.float32))
 
@@ -204,21 +229,16 @@ def _serve_kernel(xf, xi, xv, consts, qt0, ex0, tbl0, busy0, fin0, head0,
         misc[...] = misc0[...]
         sti[...] = st0[...]
 
-    c = consts[...]
-    s = SoCStatic(*[c[j] for j in range(N_STATIC)])
-    learned = c[N_STATIC] != 0.0
-    weights = rewards.RewardWeights(
-        x=c[N_STATIC + 1], y=c[N_STATIC + 2], z=c[N_STATIC + 3])
-    sp = ServeParams(*[c[N_CONSTS + j]
-                       for j in range(len(ServeParams._fields))])
+    s, learned, weights, extra = _consts(consts)
+    sp = ServeParams(*extra)
     geom, warm_cap = derive_geom(s)
 
     # Serving slots are accelerators, so the packed row's placeholder
     # others column has width n_accs (serve_step overwrites it anyway).
-    x = unpack_inputs(xf[...][0], xi[...][0], n_tiles=n_tiles,
-                      n_threads=n_accs, n_actions=n_actions,
-                      faulted=faulted)
-    v = xv[...][0]
+    r = i % ROWS
+    x = _row_inputs(xf, xi, r, n_tiles=n_tiles, n_threads=n_accs,
+                    n_actions=n_actions, faulted=faulted)
+    v = [xv[r, k] for k in range(xv.shape[1])]
 
     carry = ServeCarry(
         qtable=qt[...], extrema=ex[...], tbl=tbl[...], busy=busy[...][0],
@@ -236,7 +256,7 @@ def _serve_kernel(xf, xi, xv, consts, qt0, ex0, tbl0, busy0, fin0, head0,
     head[...] = carry.head[None, :]
     misc[...] = jnp.stack([carry.pressure, carry.tripped]).reshape(1, 2)
     sti[...] = carry.step.reshape(1, 1)
-    y_out[...] = y[None, :]
+    y_out[pl.ds(r, 1), :] = y[None, :]
 
     @pl.when(i == n_steps - 1)
     def _finish():
@@ -274,16 +294,12 @@ def soc_step_serve(xf, xi, xv, consts, carry0: ServeCarry, *,
     n_states, _ = qt_shape = carry0.qtable.shape
     n_accs = carry0.busy.shape[0]
     queue_cap = carry0.fin.shape[-1]
-    n_actions_q = qt_shape[1]
-
-    row = lambda width: pl.BlockSpec((1, width), lambda i: (i, 0))
-    full = lambda shape: pl.BlockSpec(shape, lambda i: (0,) * len(shape))
 
     carry_specs = [
-        full(qt_shape), full((4, n_accs)),
-        full((n_accs, tbl_width(n_tiles))), full((1, n_accs)),
-        full((n_accs, queue_cap)), full((1, n_accs)), full((1, 2)),
-        full((1, 1)),
+        _whole(qt_shape), _whole((4, n_accs)),
+        _whole((n_accs, tbl_width(n_tiles))), _whole((1, n_accs)),
+        _whole((n_accs, queue_cap)), _whole((1, n_accs)), _whole((1, 2)),
+        _whole((1, 1)),
     ]
     carry_shapes = [
         jax.ShapeDtypeStruct(qt_shape, jnp.float32),
@@ -301,9 +317,10 @@ def soc_step_serve(xf, xi, xv, consts, carry0: ServeCarry, *,
                           ddr_attribution=ddr_attribution,
                           faulted=faulted),
         grid=(n_steps,),
-        in_specs=[row(n_f), row(n_i), row(3), full((N_SERVE_CONSTS,))]
-        + carry_specs,
-        out_specs=[row(len(SERVE_YCOLS))] + carry_specs,
+        in_specs=[_rows(n_f), _rows(n_i, pltpu.SMEM),
+                  _rows(3, pltpu.SMEM),
+                  pl.BlockSpec(memory_space=pltpu.SMEM)] + carry_specs,
+        out_specs=[_rows(len(SERVE_YCOLS))] + carry_specs,
         out_shape=[jax.ShapeDtypeStruct((n_steps, len(SERVE_YCOLS)),
                                         jnp.float32)] + carry_shapes,
         scratch_shapes=[
@@ -317,7 +334,8 @@ def soc_step_serve(xf, xi, xv, consts, carry0: ServeCarry, *,
             pltpu.VMEM((1, 1), jnp.int32),
         ],
         interpret=interpret,
-    )(xf, xi, xv, consts, carry0.qtable, carry0.extrema, carry0.tbl,
+    )(xf, xi, xv, consts[None, :], carry0.qtable, carry0.extrema,
+      carry0.tbl,
       carry0.busy.reshape(1, n_accs), carry0.fin,
       carry0.head.reshape(1, n_accs),
       jnp.stack([carry0.pressure, carry0.tripped]).reshape(1, 2),

@@ -47,6 +47,7 @@ import numpy as np
 from repro.core import qlearn, rewards, state as cstate
 from repro.core.modes import CoherenceMode
 from repro.core.state import CacheGeometry
+from repro.core.vops import iota, put_row, take, take_row
 from repro.soc import nn as socnn
 from repro.soc.faults import StepFault
 from repro.soc.memsys import SoCStatic, invocation_perf_cached, warmth_after
@@ -72,14 +73,17 @@ def tbl_width(n_tiles: int) -> int:
 
 def init_slot_table(n_threads: int, n_tiles: int) -> jnp.ndarray:
     """Fresh packed slot table: mode=-1 (never used), warmth=1, rest 0."""
-    tbl = jnp.zeros((n_threads, tbl_width(n_tiles)), jnp.float32)
-    return tbl.at[:, TBL_MODE].set(-1.0).at[:, TBL_WARM].set(1.0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (n_threads, tbl_width(n_tiles)),
+                                   1)
+    return jnp.where(col == TBL_MODE, np.float32(-1.0),
+                     jnp.where(col == TBL_WARM, np.float32(1.0),
+                               np.float32(0.0)))
 
 
 def _neutral_row(n_tiles: int) -> jnp.ndarray:
     """What an inactive slot reads as: mode=-1, every contribution 0."""
-    return jnp.zeros((tbl_width(n_tiles),), jnp.float32).at[TBL_MODE].set(
-        -1.0)
+    return jnp.where(iota(tbl_width(n_tiles)) == TBL_MODE, np.float32(-1.0),
+                     np.float32(0.0))
 
 
 class StepInputs(NamedTuple):
@@ -214,7 +218,10 @@ def fused_step(s: SoCStatic, geom: CacheGeometry, warm_cap, learned,
     omask = x.others & (tbl[:, TBL_MODE] >= 0.0)
     # ONE masked read serves sense, timing and DDR attribution: inactive
     # slots become the neutral row (mode -1, zero contributions).
-    otbl = jnp.where(omask[:, None], tbl, _neutral_row(n_tiles))
+    # (The mask goes to a column as int32: the TPU kernel cannot reshape
+    # a bool vector.)
+    otbl = jnp.where(omask.astype(jnp.int32)[:, None] != 0, tbl,
+                     _neutral_row(n_tiles))
     omodes = otbl[:, TBL_MODE]
     ofps = otbl[:, TBL_FP]
     odram = otbl[:, TBL_DRAM]
@@ -226,11 +233,11 @@ def fused_step(s: SoCStatic, geom: CacheGeometry, warm_cap, learned,
         target_tiles=x.tiles, target_footprint=x.footprint, geom=geom,
         active_fp_per_tile=ofpt)
 
-    self_row = tbl[x.thread]
+    self_row = take_row(tbl, x.thread)
     warm_t = jnp.where(x.fresh, 1.0, self_row[TBL_WARM])
 
     # One shared Q-row gather: selection and update read identical floats.
-    row = qtable[state_idx]
+    row = take_row(qtable, state_idx)
     if wpack is None:
         row_sel = row
         learned_eff = learned
@@ -258,7 +265,8 @@ def fused_step(s: SoCStatic, geom: CacheGeometry, warm_cap, learned,
     # Degradation safety: a non-finite sense feature (a fault-corrupted
     # footprint) forces the always-available non-coherent mode, like an
     # unavailable action.  ``& True`` on the healthy path is bitwise-free.
-    mode = jnp.where(x.avail[action] & jnp.isfinite(x.footprint), action,
+    mode = jnp.where(take(x.avail, action) & jnp.isfinite(x.footprint),
+                     action,
                      int(CoherenceMode.NON_COH_DMA)).astype(jnp.int32)
     fault = None
     if x.f_exec is not None:
@@ -308,8 +316,8 @@ def fused_step(s: SoCStatic, geom: CacheGeometry, warm_cap, learned,
         new_slot = jnp.where(x.valid, new_slot, self_row)
         rs_new = jax.tree_util.tree_map(
             lambda n, o: jnp.where(x.valid, n, o), rs_new, rs)
-    qtable_new = qtable.at[state_idx].set(new_qrow)
-    tbl_new = tbl.at[x.thread].set(new_slot)
+    qtable_new = put_row(qtable, state_idx, new_qrow)
+    tbl_new = put_row(tbl, x.thread, new_slot)
 
     y = jnp.stack([mode.astype(jnp.float32), state_idx.astype(jnp.float32),
                    action.astype(jnp.float32), m.exec_time,
@@ -411,11 +419,6 @@ def init_serve_carry(qtable0, extrema0, n_accs: int, n_tiles: int,
     )
 
 
-def _iota1d(n: int) -> jnp.ndarray:
-    # TPU requires >= 2D iota; squeeze back to the 1-D index vector.
-    return jax.lax.broadcasted_iota(jnp.int32, (n, 1), 0).squeeze(-1)
-
-
 def _backoff_cycles(backoff, retries: int):
     # soc.faults.backoff_cycles with a static retry count (np scalar so it
     # inlines as a literal under Pallas tracing); exp2 of a small integer
@@ -461,8 +464,8 @@ def serve_step(s: SoCStatic, geom: CacheGeometry, warm_cap, learned,
     acc = x.acc_id
     n_accs = carry.busy.shape[0]
     queue_cap = carry.fin.shape[-1]
-    busy_a = carry.busy[acc]
-    frow = carry.fin[acc]
+    busy_a = take(carry.busy, acc)
+    frow = take_row(carry.fin, acc)
     degraded = carry.tripped != 0.0
     live = sp.frozen == 0.0
 
@@ -476,10 +479,14 @@ def serve_step(s: SoCStatic, geom: CacheGeometry, warm_cap, learned,
         start_r = jnp.maximum(t_r, busy_a)
         oks.append((depth_r < cap_eff) & (start_r <= deadline))
         starts.append(start_r)
-    ok = jnp.stack(oks)
-    executed = jnp.any(ok)
-    attempt = jnp.argmax(ok).astype(jnp.int32)
-    start = jnp.stack(starts)[attempt]
+    # The first admissible attempt (attempt 0 when none is), as scalar
+    # selects: the Pallas TPU kernel cannot stack bools or argmax them.
+    executed = jnp.zeros((), bool)
+    attempt, start = jnp.zeros((), jnp.int32), starts[0]
+    for r in reversed(range(_SERVE_MAX_RETRIES + 1)):
+        executed = executed | oks[r]
+        attempt = jnp.where(oks[r], r, attempt)
+        start = jnp.where(oks[r], starts[r], start)
     retries = jnp.where(executed, attempt.astype(f32), _SHED_RETRIES)
     depth0 = jnp.sum((frow > t_arr).astype(f32))
 
@@ -493,7 +500,7 @@ def serve_step(s: SoCStatic, geom: CacheGeometry, warm_cap, learned,
     # Forced NON_COH under overload: learned routes through the pre_mode
     # branch, and the Q update stays on-policy (the observed action IS
     # NON_COH while degraded).
-    others = (carry.busy > start) & (_iota1d(n_accs) != acc)
+    others = (carry.busy > start) & (iota(n_accs) != acc)
     si = x._replace(
         thread=acc, fresh=jnp.ones((), bool), others=others,
         valid=executed, eps=eps, alpha=alpha,
@@ -523,12 +530,14 @@ def serve_step(s: SoCStatic, geom: CacheGeometry, warm_cap, learned,
     ex_f = executed.astype(f32)
     exec_time = y[3]
     finish = start + exec_time
-    slot_hot = (_iota1d(queue_cap) == carry.head[acc]) & executed
-    fin = carry.fin.at[acc].set(jnp.where(slot_hot, finish, frow))
-    nxt = carry.head[acc] + 1
-    head = carry.head.at[acc].set(jnp.where(
-        executed, jnp.where(nxt >= queue_cap, 0, nxt), carry.head[acc]))
-    busy = carry.busy.at[acc].set(jnp.where(executed, finish, busy_a))
+    head_a = take(carry.head, acc)
+    slot_hot = (iota(queue_cap) == head_a) & executed
+    fin = put_row(carry.fin, acc, jnp.where(slot_hot, finish, frow))
+    nxt = head_a + 1
+    acc_hot = iota(n_accs) == acc
+    head = jnp.where(acc_hot & executed, jnp.where(nxt >= queue_cap, 0, nxt),
+                     carry.head)
+    busy = jnp.where(acc_hot & executed, finish, carry.busy)
 
     # ---- overload watchdog --------------------------------------------
     pressure = ((1.0 - sp.pressure_beta) * carry.pressure

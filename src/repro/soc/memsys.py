@@ -31,6 +31,7 @@ import jax.numpy as jnp
 
 from repro.core.modes import CoherenceMode
 from repro.core.rewards import Measurement
+from repro.core.vops import vsum
 from repro.soc.accelerators import IRREGULAR, PF, STREAMING
 from repro.soc.config import SoCConfig
 
@@ -262,8 +263,8 @@ def invocation_perf_cached(
     dram_cap = s.dram_bw * n_my_tiles
     llc_cap = s.llc_bw * n_my_tiles
 
-    dram_load = jnp.sum(jnp.where(other_active, od_dram * overlap, 0.0))
-    llc_load = jnp.sum(jnp.where(other_active, od_llc * overlap, 0.0))
+    dram_load = vsum(jnp.where(other_active, od_dram * overlap, 0.0))
+    llc_load = vsum(jnp.where(other_active, od_llc * overlap, 0.0))
     if fault is not None:
         llc_load = llc_load + fault.llc_extra
     dram_slow = jnp.maximum(1.0, (dram_load + my_dram_demand) / dram_cap)
@@ -271,7 +272,7 @@ def invocation_perf_cached(
 
     # LLC capacity share: my footprint vs all cached footprints on my tiles.
     other_cached = other_active & (other_modes != int(CoherenceMode.NON_COH_DMA))
-    cached_fp = jnp.sum(
+    cached_fp = vsum(
         jnp.where(other_cached, other_footprints * overlap, 0.0)
     )
     llc_capacity = (
@@ -280,7 +281,7 @@ def invocation_perf_cached(
     my_llc_cap = llc_capacity * footprint / jnp.maximum(footprint + cached_fp, 1.0)
 
     # Directory serialization: other requesters holding the LLC controller.
-    n_llc_users = jnp.sum(jnp.where(other_cached, overlap, 0.0))
+    n_llc_users = vsum(jnp.where(other_cached, overlap, 0.0))
 
     # ------------------------------------------------------------------
     # Shared path bandwidths.
@@ -318,13 +319,14 @@ def invocation_perf_cached(
     full_flush_bytes = warm_frac * jnp.minimum(footprint, hierarchy)
     priv_flush_bytes = warm_frac * jnp.minimum(footprint, s.n_cpus * s.l2_bytes)
     ovh_base = s.driver_base + tlb
-    ovh = jnp.select(
-        [mode == int(CoherenceMode.NON_COH_DMA),
-         mode == int(CoherenceMode.LLC_COH_DMA)],
-        [ovh_base + s.flush_base + full_flush_bytes / s.flush_bw,
-         ovh_base + s.flush_base + priv_flush_bytes / s.flush_bw],
-        ovh_base,
-    )
+    # Nested wheres, not jnp.select: select lowers through an argmax over
+    # bool conditions, which the Pallas TPU kernel cannot compile.
+    ovh = jnp.where(
+        mode == int(CoherenceMode.NON_COH_DMA),
+        ovh_base + s.flush_base + full_flush_bytes / s.flush_bw,
+        jnp.where(mode == int(CoherenceMode.LLC_COH_DMA),
+                  ovh_base + s.flush_base + priv_flush_bytes / s.flush_bw,
+                  ovh_base))
     if fault is not None:
         ovh = ovh + fault.retry_cycles
 
@@ -400,20 +402,14 @@ def invocation_perf_cached(
     )
     fc_off = fc_llc_miss + fc_evict + fc_write_off
 
-    comm_cycles = jnp.select(
-        [mode == int(CoherenceMode.NON_COH_DMA),
-         mode == int(CoherenceMode.LLC_COH_DMA),
-         mode == int(CoherenceMode.COH_DMA)],
-        [nc_comm, lc_comm, cd_comm],
-        fc_comm,
-    )
-    offchip_bytes = jnp.select(
-        [mode == int(CoherenceMode.NON_COH_DMA),
-         mode == int(CoherenceMode.LLC_COH_DMA),
-         mode == int(CoherenceMode.COH_DMA)],
-        [nc_offchip, lc_off, cd_off],
-        fc_off,
-    )
+    def by_mode(nc, lc, cd, fc):
+        return jnp.where(
+            mode == int(CoherenceMode.NON_COH_DMA), nc,
+            jnp.where(mode == int(CoherenceMode.LLC_COH_DMA), lc,
+                      jnp.where(mode == int(CoherenceMode.COH_DMA), cd, fc)))
+
+    comm_cycles = by_mode(nc_comm, lc_comm, cd_comm, fc_comm)
+    offchip_bytes = by_mode(nc_offchip, lc_off, cd_off, fc_off)
 
     compute_cycles = compute_per_byte * footprint * reuse
     hi = jnp.maximum(compute_cycles, comm_cycles)

@@ -55,6 +55,7 @@ from repro.core import qlearn
 from repro.core.modes import N_MODES
 from repro.core.policies import Policy
 from repro.core.state import N_STATES
+from repro.core.vops import iota, vsum
 from repro.soc.accelerators import IRREGULAR, PF, PROFILE_WIDTH
 
 # Number of normalized sense features (the "sense" embedding).  Order is
@@ -87,11 +88,7 @@ class MLPConfig:
 
 # Static registration: MLPConfig becomes treedef, not leaves — jit keys
 # on it and vmap/tree_map pass it through untouched.
-try:
-    jax.tree_util.register_static(MLPConfig)
-except AttributeError:  # older jax: empty-children node with aux=self
-    jax.tree_util.register_pytree_node(
-        MLPConfig, lambda c: ((), c), lambda aux, _: aux)
+jax.tree_util.register_static(MLPConfig)
 
 
 class MLPQState(NamedTuple):
@@ -123,11 +120,6 @@ def pack_shape(dims: Sequence[int]) -> tuple:
     Layer ``l`` occupies ``dims[l]`` weight rows followed by one bias
     row; columns pad to the widest output so one rectangle holds all."""
     return sum(d + 1 for d in dims[:-1]), max(dims[1:])
-
-
-def _iota1d(n: int) -> jnp.ndarray:
-    # TPU requires >= 2D iota; squeeze back to the 1-D index vector.
-    return jax.lax.broadcasted_iota(jnp.int32, (n, 1), 0).squeeze(-1)
 
 
 def forward_packed(wpack, x, dims) -> jnp.ndarray:
@@ -175,7 +167,7 @@ def td_update_packed(wpack, x, action, reward, lr_eff, dims, gate):
 
     f32 = jnp.float32
     n_act = dims[-1]
-    hot = (_iota1d(n_act) == action).astype(f32)
+    hot = (iota(n_act) == action).astype(f32)
     q_a = jnp.sum(hs[-1] * hot)
     delta = q_a - reward
 
@@ -221,7 +213,7 @@ def step_features(feats: str, s, state_idx, *, footprint, tiles, omask,
     path; the serving step feeds real values)."""
     f32 = jnp.float32
     if feats == "onehot":
-        return (_iota1d(N_STATES) == state_idx).astype(f32)
+        return (iota(N_STATES) == state_idx).astype(f32)
     llc_total = s.llc_slice_bytes * s.n_mem_tiles
     n_tiles = tiles.shape[-1]
     fp = footprint.astype(f32) if hasattr(footprint, "astype") else f32(footprint)
@@ -235,11 +227,11 @@ def step_features(feats: str, s, state_idx, *, footprint, tiles, omask,
         jnp.clip(fp / s.l2_bytes, 0.0, 4.0) * np.float32(0.25),
         jnp.clip(fp / llc_total, 0.0, 4.0) * np.float32(0.25),
         jnp.sum(tiles.astype(f32)) / np.float32(n_tiles),
-        jnp.sum(omask_f) * np.float32(0.125),
-        jnp.sum(cached.astype(f32)) * np.float32(0.125),
-        jnp.sum(non_coh.astype(f32)) * np.float32(0.125),
-        jnp.clip(jnp.sum(ofps) / llc_total, 0.0, 4.0) * np.float32(0.25),
-        jnp.clip(jnp.sum(odram) / s.dram_bw, 0.0, 4.0) * np.float32(0.25),
+        vsum(omask_f) * np.float32(0.125),
+        vsum(cached.astype(f32)) * np.float32(0.125),
+        vsum(non_coh.astype(f32)) * np.float32(0.125),
+        jnp.clip(vsum(ofps) / llc_total, 0.0, 4.0) * np.float32(0.25),
+        jnp.clip(vsum(odram) / s.dram_bw, 0.0, 4.0) * np.float32(0.25),
         warm_t,
         (profile[PF.PATTERN] == np.float32(IRREGULAR)).astype(f32),
         jnp.log2(1.0 + profile[PF.COMPUTE]) * np.float32(0.125),

@@ -19,9 +19,11 @@ slice of the batch:
   * :func:`sharded_episodes` shards ``StackedVecEnv.episodes`` over the
     policy axis N of its (K, N) spec grid.
 
-Whenever the mesh has a single device — or the batch axis does not divide
-the device count — the wrappers fall back to the plain vmap call, which
-is bitwise-identical by construction.  ``force_shard_map=True`` runs
+Whenever the mesh has a single device the wrappers fall back to the
+plain vmap call, which is bitwise-identical by construction.  On a mesh
+of several devices the batch axis must divide the device count: the
+wrappers raise rather than quietly run the whole batch on one device.
+``force_shard_map=True`` runs
 shard_map even on one device; that path recompiles the program under the
 shard_map wrapper, so float leaves agree with vmap to roundoff (~1e-7,
 XLA refuses in a different order) while integer state (visits, step
@@ -30,7 +32,6 @@ counters, modes) stays bitwise — the equivalence tests pin both.
 from __future__ import annotations
 
 import jax
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.distributed.sharding import lane_mesh
@@ -74,8 +75,8 @@ def _shard_call(fn, mesh: Mesh, args, in_axes, out_axis: int, consts=()):
             return jitted(*args)
     in_specs = tuple(_axis_spec(a, ax) for a, ax in zip(args, in_axes))
     out_specs = _axis_spec(jax.eval_shape(fn, *args), out_axis)
-    sharded = shard_map(fn, mesh=mesh, in_specs=in_specs,
-                        out_specs=out_specs, check_rep=False)
+    sharded = jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                            out_specs=out_specs, check_vma=False)
     jitted = jax.jit(sharded)
     _JIT_CACHE.append((tuple(consts), mesh_key, in_axes, out_axis, jitted))
     return jitted(*args)
@@ -85,8 +86,12 @@ def _use_mesh(mesh: Mesh | None, batch: int, force: bool):
     """Resolve the mesh; None means 'fall back to plain vmap'."""
     mesh = lane_mesh() if mesh is None else mesh
     n = int(mesh.devices.size)
-    if batch % n != 0 or (n == 1 and not force):
-        return None
+    if n == 1:
+        return mesh if force else None
+    if batch % n != 0:
+        raise ValueError(
+            f"batch of {batch} does not divide over the {n}-device mesh; "
+            f"pad it to a multiple of {n} or pass a smaller mesh")
     return mesh
 
 
@@ -98,8 +103,8 @@ def sharded_train_batched(env, train_apps, cfg, weights_batch, keys, *,
 
     Same signature and results as the method; ``mesh`` defaults to
     :func:`lane_mesh` over all devices.  Falls back to the plain vmap
-    call when the mesh is a single device (unless ``force_shard_map``)
-    or B does not divide the device count.
+    call when the mesh is a single device (unless ``force_shard_map``);
+    on a larger mesh B must divide the device count.
 
     ``faults`` (a ``soc.faults.FaultSpec``) replicates to every device as
     a *traced* argument (``P()``), so sweeping fault intensities reuses

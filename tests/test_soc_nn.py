@@ -25,7 +25,7 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from repro.core import qlearn
+from repro.core import qlearn, vops
 from repro.checkpoint.manager import CheckpointManager
 from repro.soc import nn as socnn, vecenv as vec
 from repro.soc.apps import make_phase
@@ -83,7 +83,7 @@ def test_onehot_distillation_reproduces_table_rows_exactly():
     mlp = socnn.mlp_from_qtable(qtable)
     dims = socnn.mlp_dims(mlp.cfg)
     for s in (0, 7, 100, 242):
-        x = (socnn._iota1d(243) == s).astype(jnp.float32)
+        x = (vops.iota(243) == s).astype(jnp.float32)
         row = socnn.forward_packed(mlp.wpack, x, dims)
         np.testing.assert_array_equal(np.asarray(row),
                                       np.asarray(qtable[s]))

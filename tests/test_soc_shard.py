@@ -134,3 +134,77 @@ def test_episodes_fallback_bitwise_and_forced_close(stacked_setup):
     np.testing.assert_array_equal(np.asarray(direct.state_idx),
                                   np.asarray(forced.state_idx))
     _tree_close(direct, forced)
+
+
+# ------------------------------------------------ four virtual devices ----
+# A subprocess gets four CPU devices (the main pytest process keeps its
+# one-device view): the lane-sharded trainer must split the agents over
+# all four and match the single-device vmap call.
+_FOUR_DEVICE_SCRIPT = r"""
+import jax, jax.numpy as jnp, numpy as np
+from repro.core import qlearn, rewards
+from repro.soc import shard
+from repro.soc.apps import make_phase
+from repro.soc.config import SOC_MOTIV_ISO, SOC_MOTIV_PAR
+from repro.soc.des import Application, SoCSimulator
+from repro.soc.stacked import StackedVecEnv
+
+assert jax.device_count() == 4, jax.devices()
+sims = [SoCSimulator(SOC_MOTIV_ISO, seed=1), SoCSimulator(SOC_MOTIV_PAR, seed=1)]
+env = StackedVecEnv.from_simulators(sims)
+apps = []
+for sim in sims:
+    rng = np.random.default_rng(5)
+    apps.append(Application(name="four-device", phases=[
+        make_phase(rng, sim.soc, name=f"p{i}", n_threads=2, size_classes=[c],
+                   chain_len=2, loops=2) for i, c in enumerate(("S", "M"))]))
+its = [env.compile(apps, seed=it) for it in range(2)]
+cfg = qlearn.QConfig(decay_steps=jnp.asarray(
+    [s * 2 for s in its[0].n_steps], jnp.int32))
+
+def agents(b):
+    return (rewards.stack_weights([rewards.PAPER_DEFAULT_WEIGHTS] * b),
+            env._default_keys(env.n_lanes, b))
+
+wb, keys = agents(8)
+qs4, _ = shard.sharded_train_batched_stacked(env, its, cfg, wb, keys)
+qs1, _ = env.train_batched(its, cfg, wb, keys)
+for a, b in zip(jax.tree_util.tree_leaves(qs4), jax.tree_util.tree_leaves(qs1)):
+    a, b = np.asarray(a), np.asarray(b)
+    if np.issubdtype(a.dtype, np.floating):
+        np.testing.assert_allclose(a, b, rtol=0, atol=1e-6)
+    else:
+        np.testing.assert_array_equal(a, b)
+sh = qs4.qtable.sharding
+assert len(sh.device_set) == 4 and not sh.is_fully_replicated, sh
+print("SHARDED_OK")
+try:
+    shard.sharded_train_batched_stacked(env, its, cfg, *agents(6))
+except ValueError as e:
+    print("RAISES_OK", e)
+"""
+
+
+@pytest.fixture(scope="module")
+def four_device_run():
+    import os
+    import subprocess
+    import sys
+
+    env = dict(os.environ, PYTHONPATH="src", JAX_PLATFORMS="cpu",
+               XLA_FLAGS="--xla_force_host_platform_device_count=4")
+    return subprocess.run(
+        [sys.executable, "-c", _FOUR_DEVICE_SCRIPT], env=env,
+        capture_output=True, text=True, timeout=600,
+        cwd=os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
+
+def test_stacked_train_sharded_over_four_devices(four_device_run):
+    assert "SHARDED_OK" in four_device_run.stdout, (
+        four_device_run.stderr[-3000:])
+
+
+def test_batch_not_dividing_multi_device_mesh_raises(four_device_run):
+    assert "RAISES_OK" in four_device_run.stdout, (
+        four_device_run.stderr[-3000:])
+    assert "does not divide over the 4-device mesh" in four_device_run.stdout
