@@ -18,11 +18,11 @@ TPU), and then makes the same call on an environment built with
 
 Checks: the deterministic families (fixed modes, manual) agree per phase
 in time and off-chip accesses within 1e-4 relative; serving admits and
-sheds the same requests; the learned agents' mode-match share and largest
-Q-table difference are printed.  ``--chips 4`` runs only
-``shard.sharded_train_batched_stacked`` over four chips against the same
-call on one: integer state bitwise, floats within 1e-6, the output
-sharded over all four.
+sheds the same requests; the trained Q-tables agree within 1e-5 and the
+learned agents choose the same mode on at least 99% of invocations.
+``--chips 4`` runs only ``shard.sharded_train_batched_stacked`` over four
+chips against the same call on one: integer state bitwise, floats within
+1e-6, the output sharded over all four.
 
 Each call is timed twice: ``setup_s`` is the first call (compilation
 included), ``wall_s`` the second.  The last line of standard output is
@@ -50,6 +50,8 @@ import numpy as np  # noqa: E402
 
 REL_TOL = 1e-4       # deterministic families, kernel vs unfused scan
 SHARD_ATOL = 1e-6    # float leaves, four chips vs one
+QTABLE_ATOL = 1e-5   # trained Q-tables, kernel vs unfused scan
+MIN_MATCH = 0.99     # learned agents' mode-match share, kernel vs scan
 N_PHASES = 8         # the full Fig. 9 application length
 ITERS = 3
 SEEDS = 4
@@ -127,8 +129,8 @@ def phase_train(fx: Fig9Setup) -> tuple[bool, object]:
                              - np.asarray(qs_ref.qtable))))
     print(f"train check: agents={visits.size} visit_totals_exact="
           f"{counts_ok} qtable_finite={finite} "
-          f"qtable_max_abs_diff_vs_ref={dq:.6g}")
-    return counts_ok and finite, qs
+          f"qtable_max_abs_diff_vs_ref={dq:.6g} (tol {QTABLE_ATOL:g})")
+    return counts_ok and finite and dq <= QTABLE_ATOL, qs
 
 
 def phase_eval(fx: Fig9Setup, qs) -> bool:
@@ -166,13 +168,14 @@ def phase_eval(fx: Fig9Setup, qs) -> bool:
     match = float((same[learned] & valid).sum()
                   / np.broadcast_to(valid, same[learned].shape).sum())
     det_same = bool(np.all(same[:, :n_det] | ~valid))
-    ok = rel_t <= REL_TOL and rel_o <= REL_TOL and det_same
+    ok = (rel_t <= REL_TOL and rel_o <= REL_TOL and det_same
+          and match >= MIN_MATCH)
     print(f"eval check: lanes={env.n_lanes} policies="
           f"{specs.learned.shape[1]} deterministic max_rel_phase_time="
           f"{rel_t:.3g} max_rel_phase_offchip={rel_o:.3g} (tol {REL_TOL:g}) "
           f"modes_identical={det_same}")
     print(f"eval check: learned (random + trained) mode_match_share="
-          f"{match:.6f}")
+          f"{match:.6f} (min {MIN_MATCH:g})")
     return ok
 
 

@@ -219,27 +219,16 @@ def _eval_keys(seeds: np.ndarray, n_policies: int) -> jnp.ndarray:
 
 
 def _bucket_norms(sub: StackedVecEnv, st_iters, st_eval,
-                  seeds_g: np.ndarray, iters: int, sharded: bool = False
+                  seeds_g: np.ndarray, iters: int
                   ) -> tuple[np.ndarray, np.ndarray]:
     """Train one agent per lane, then evaluate the whole suite in one
-    episodes call; returns (norm_time, norm_mem), each (K_g, N).
-
-    ``sharded`` routes the training call through
-    :func:`repro.soc.shard.sharded_train_batched_stacked`, splitting the
-    agent axis across every visible device; on a single device the
-    wrapper falls back to the plain vmap call bitwise-identically."""
+    episodes call; returns (norm_time, norm_mem), each (K_g, N)."""
     cfg = qlearn.QConfig(decay_steps=jnp.asarray(
         [s * iters for s in st_iters[0].n_steps], jnp.int32))
     tkeys = jax.vmap(jax.random.PRNGKey)(
         jnp.asarray(seeds_g, jnp.uint32)).reshape(len(seeds_g), 1, 2)
-    if sharded:
-        from repro.soc import shard
-        qs, _ = shard.sharded_train_batched_stacked(
-            sub, st_iters, cfg, stack_weights([PAPER_DEFAULT_WEIGHTS]),
-            tkeys)
-    else:
-        qs, _ = sub.train_batched(
-            st_iters, cfg, stack_weights([PAPER_DEFAULT_WEIGHTS]), tkeys)
+    qs, _ = sub.train_batched(
+        st_iters, cfg, stack_weights([PAPER_DEFAULT_WEIGHTS]), tkeys)
 
     suite = [FixedHomogeneous(m) for m in CoherenceMode]
     suite += [RandomPolicy(), ManualPolicy()]
@@ -286,7 +275,7 @@ def rank_axes(samples: Sequence[SampledSoC],
 
 def run_sweep(samples: Sequence[SampledSoC], *, iters: int = 3,
               n_phases: int = 3, max_buckets: int = 4,
-              min_gain: float = 0.02, sharded: bool = False) -> dict:
+              min_gain: float = 0.02) -> dict:
     """Train + evaluate every sampled SoC in at most ``max_buckets``
     batched (train, eval) call pairs and reduce to per-architecture win
     margins.
@@ -300,11 +289,7 @@ def run_sweep(samples: Sequence[SampledSoC], *, iters: int = 3,
     keys, so every per-SoC input — and every deterministic-family
     metric — is independent of bucketing; keyed families (random,
     cohmeleon) consume noise pre-sampled at the bucket's padded scan
-    length, so their draws differ across bucket layouts.
-
-    ``sharded=True`` splits each bucket's training call across every
-    visible device (:mod:`repro.soc.shard`); with one device it falls
-    back to the plain call, bitwise-identical by construction."""
+    length, so their draws differ across bucket layouts."""
     from repro.soc.apps import make_application
 
     socs = [s.config for s in samples]
@@ -346,7 +331,7 @@ def run_sweep(samples: Sequence[SampledSoC], *, iters: int = 3,
                                     socs_g) for it in range(iters)]
         st_eval = _stack_compiled([compiled_eval[i] for i in g], socs_g)
         parts.append(_bucket_norms(sub, st_iters, st_eval,
-                                   seeds[list(g)], iters, sharded))
+                                   seeds[list(g)], iters))
     nt = reassemble_lanes(groups, [p[0] for p in parts])
     nm = reassemble_lanes(groups, [p[1] for p in parts])
     t_run = time.perf_counter() - t0

@@ -269,21 +269,6 @@ def test_sweep_results_independent_of_bucket_count():
         assert (out["norm_time"] > 0).all()
 
 
-def test_sweep_sharded_flag_single_device_is_bitwise():
-    """``run_sweep(sharded=True)`` routes each bucket's training through
-    ``shard.sharded_train_batched_stacked``; on a single device the
-    wrapper falls back to the plain vmap call, so the whole sweep output
-    must be bitwise-identical to ``sharded=False``."""
-    samples = dse.sample_socs(13, 4)
-    plain = dse.run_sweep(samples, iters=2, n_phases=2, max_buckets=2,
-                          min_gain=0.0)
-    shard = dse.run_sweep(samples, iters=2, n_phases=2, max_buckets=2,
-                          min_gain=0.0, sharded=True)
-    np.testing.assert_array_equal(plain["norm_time"], shard["norm_time"])
-    np.testing.assert_array_equal(plain["norm_mem"], shard["norm_mem"])
-    assert plain["groups"] == shard["groups"]
-
-
 def test_rank_axes_recovers_a_planted_signal():
     samples = dse.sample_socs(0, 48)
     y = np.asarray([0.5 * s.axes["no_l2_frac"] - 0.05 for s in samples])

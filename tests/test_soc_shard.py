@@ -182,6 +182,12 @@ try:
     shard.sharded_train_batched_stacked(env, its, cfg, *agents(6))
 except ValueError as e:
     print("RAISES_OK", e)
+
+import json
+from repro.soc import dse
+out = dse.run_sweep(dse.sample_socs(13, 3), iters=2, n_phases=2,
+                    max_buckets=1)
+print("SWEEP_OK", json.dumps(np.asarray(out["norm_time"]).tolist()))
 """
 
 
@@ -208,3 +214,19 @@ def test_batch_not_dividing_multi_device_mesh_raises(four_device_run):
     assert "RAISES_OK" in four_device_run.stdout, (
         four_device_run.stderr[-3000:])
     assert "does not divide over the 4-device mesh" in four_device_run.stdout
+
+
+def test_run_sweep_on_four_devices_matches_one(four_device_run):
+    """``dse.run_sweep`` trains one agent per lane unsharded, so a host
+    with four devices must give exactly the one-device sweep."""
+    import json
+
+    from repro.soc import dse
+
+    line = next((ln for ln in four_device_run.stdout.splitlines()
+                 if ln.startswith("SWEEP_OK ")), None)
+    assert line is not None, four_device_run.stderr[-3000:]
+    got = np.asarray(json.loads(line[len("SWEEP_OK "):]))
+    want = dse.run_sweep(dse.sample_socs(13, 3), iters=2, n_phases=2,
+                         max_buckets=1)["norm_time"]
+    np.testing.assert_array_equal(got, np.asarray(want, got.dtype))
