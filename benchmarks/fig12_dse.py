@@ -99,7 +99,6 @@ def run(quick: bool = False, n: int | None = None, max_buckets: int = 4,
         },
         "budget": dataclasses.asdict(DEFAULT_BUDGET),
         "waste": out["waste"],
-        "throughput": out["timing"],
         "_headline": {
             "mean_speedup_vs_noncoh":
                 float(np.mean(margins["speedup_vs_noncoh"])),

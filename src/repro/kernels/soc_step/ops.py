@@ -20,7 +20,9 @@ this kernel's sequential grid only pays off where VMEM scratch is real:
 
 Both lowerings share :func:`~repro.kernels.soc_step.ref.fused_step` and
 the :func:`~repro.kernels.soc_step.ref.pack_inputs` row layout, so they
-agree to float tolerance by construction (bitwise on CPU).
+agree to float tolerance by construction (bitwise on CPU).  Both entry
+points run under the ``cohm_step`` name scope: the step keeps one name
+in the compiled program whichever lowering carries it.
 """
 from __future__ import annotations
 
@@ -55,54 +57,55 @@ def fused_episode(s: SoCStatic, learned, weights, qtable0, extrema0,
     the Pallas kernel adds a VMEM-resident weights operand and appends
     ``[qfun, mlp_lr]`` to the consts row.
     """
-    mlp_dims = None
-    if mlp is not None:
-        from repro.soc import nn as socnn
-        mlp_dims = socnn.mlp_dims(mlp.cfg)
-    if kernel is None:
-        kernel = not _on_cpu()
-    if not kernel:
-        if mlp is None:
-            qtable, ys = episode_ref(
+    with jax.named_scope("cohm_step"):
+        mlp_dims = None
+        if mlp is not None:
+            from repro.soc import nn as socnn
+            mlp_dims = socnn.mlp_dims(mlp.cfg)
+        if kernel is None:
+            kernel = not _on_cpu()
+        if not kernel:
+            if mlp is None:
+                qtable, ys = episode_ref(
+                    s, learned, weights, qtable0, extrema0, xs,
+                    ddr_attribution=ddr_attribution, gated=gated)
+                return qtable, ys
+            return episode_ref(
                 s, learned, weights, qtable0, extrema0, xs,
-                ddr_attribution=ddr_attribution, gated=gated)
-            return qtable, ys
-        return episode_ref(
-            s, learned, weights, qtable0, extrema0, xs,
-            ddr_attribution=ddr_attribution, gated=gated,
-            wpack0=mlp.wpack, qfun=qfun, mlp_lr=mlp.lr,
-            mlp_dims=mlp_dims, mlp_feats=mlp.cfg.features)
-    if interpret is None:
-        interpret = _on_cpu()
+                ddr_attribution=ddr_attribution, gated=gated,
+                wpack0=mlp.wpack, qfun=qfun, mlp_lr=mlp.lr,
+                mlp_dims=mlp_dims, mlp_feats=mlp.cfg.features)
+        if interpret is None:
+            interpret = _on_cpu()
 
-    f32 = jnp.float32
-    xf, xi = pack_inputs(xs)
-    consts_parts = [
-        jnp.stack([jnp.asarray(getattr(s, f), f32)
-                   for f in SoCStatic._fields]),
-        jnp.stack([jnp.asarray(learned, f32),
-                   jnp.asarray(weights.x, f32),
-                   jnp.asarray(weights.y, f32),
-                   jnp.asarray(weights.z, f32)]),
-    ]
-    if mlp is not None:
-        consts_parts.append(jnp.stack([jnp.asarray(qfun, f32),
-                                       jnp.asarray(mlp.lr, f32)]))
-    consts = jnp.concatenate(consts_parts)
-    out = _kernel.soc_step_episode(
-        xf, xi, consts, qtable0.astype(f32), extrema0.astype(f32),
-        mlp.wpack if mlp is not None else None,
-        n_threads=xs.others.shape[-1], n_tiles=xs.tiles.shape[-1],
-        n_actions=xs.avail.shape[-1],
-        ddr_attribution=ddr_attribution, gated=gated,
-        faulted=xs.f_exec is not None,
-        interpret=interpret, mlp_dims=mlp_dims,
-        mlp_feats=mlp.cfg.features if mlp is not None else "sense")
-    if mlp is None:
-        qtable, y = out
-        return qtable, unpack_ys(y)
-    qtable, wpack, y = out
-    return qtable, wpack, unpack_ys(y)
+        f32 = jnp.float32
+        xf, xi = pack_inputs(xs)
+        consts_parts = [
+            jnp.stack([jnp.asarray(getattr(s, f), f32)
+                       for f in SoCStatic._fields]),
+            jnp.stack([jnp.asarray(learned, f32),
+                       jnp.asarray(weights.x, f32),
+                       jnp.asarray(weights.y, f32),
+                       jnp.asarray(weights.z, f32)]),
+        ]
+        if mlp is not None:
+            consts_parts.append(jnp.stack([jnp.asarray(qfun, f32),
+                                           jnp.asarray(mlp.lr, f32)]))
+        consts = jnp.concatenate(consts_parts)
+        out = _kernel.soc_step_episode(
+            xf, xi, consts, qtable0.astype(f32), extrema0.astype(f32),
+            mlp.wpack if mlp is not None else None,
+            n_threads=xs.others.shape[-1], n_tiles=xs.tiles.shape[-1],
+            n_actions=xs.avail.shape[-1],
+            ddr_attribution=ddr_attribution, gated=gated,
+            faulted=xs.f_exec is not None,
+            interpret=interpret, mlp_dims=mlp_dims,
+            mlp_feats=mlp.cfg.features if mlp is not None else "sense")
+        if mlp is None:
+            qtable, y = out
+            return qtable, unpack_ys(y)
+        qtable, wpack, y = out
+        return qtable, wpack, unpack_ys(y)
 
 
 def fused_serve_episode(s: SoCStatic, learned, weights, serve_params,
@@ -123,44 +126,45 @@ def fused_serve_episode(s: SoCStatic, learned, weights, serve_params,
     :class:`~repro.kernels.soc_step.ref.ServeCarry`.  Returns
     ``(carry_final, ys (n_requests, len(SERVE_YCOLS)))``.
     """
-    from repro.kernels.soc_step.ref import serve_episode_ref
+    with jax.named_scope("cohm_step"):
+        from repro.kernels.soc_step.ref import serve_episode_ref
 
-    if mlp is not None:
-        # nn-policy serving always takes the XLA scan: the serve kernel
-        # does not carry the weight pack (serving is admission-bound and
-        # CPU CI must never compile the kernel), and the MLP weights ride
-        # ``carry0.wpack`` so chunking/checkpointing work unchanged.
-        from repro.soc import nn as socnn
-        return serve_episode_ref(
-            s, learned, weights, serve_params, carry0, xs, t_arr, deadline,
-            priority, ddr_attribution=ddr_attribution, qfun=qfun,
-            mlp_lr=mlp.lr, mlp_dims=socnn.mlp_dims(mlp.cfg),
-            mlp_feats=mlp.cfg.features)
-    if kernel is None:
-        kernel = not _on_cpu()
-    if not kernel:
-        return serve_episode_ref(
-            s, learned, weights, serve_params, carry0, xs, t_arr, deadline,
-            priority, ddr_attribution=ddr_attribution)
-    if interpret is None:
-        interpret = _on_cpu()
+        if mlp is not None:
+            # nn-policy serving always takes the XLA scan: the serve kernel
+            # does not carry the weight pack (serving is admission-bound and
+            # CPU CI must never compile the kernel), and the MLP weights ride
+            # ``carry0.wpack`` so chunking/checkpointing work unchanged.
+            from repro.soc import nn as socnn
+            return serve_episode_ref(
+                s, learned, weights, serve_params, carry0, xs, t_arr, deadline,
+                priority, ddr_attribution=ddr_attribution, qfun=qfun,
+                mlp_lr=mlp.lr, mlp_dims=socnn.mlp_dims(mlp.cfg),
+                mlp_feats=mlp.cfg.features)
+        if kernel is None:
+            kernel = not _on_cpu()
+        if not kernel:
+            return serve_episode_ref(
+                s, learned, weights, serve_params, carry0, xs, t_arr, deadline,
+                priority, ddr_attribution=ddr_attribution)
+        if interpret is None:
+            interpret = _on_cpu()
 
-    f32 = jnp.float32
-    xf, xi = pack_inputs(xs)
-    xv = jnp.stack([jnp.asarray(t_arr, f32), jnp.asarray(deadline, f32),
-                    jnp.asarray(priority, f32)], axis=-1)
-    consts = jnp.concatenate([
-        jnp.stack([jnp.asarray(getattr(s, f), f32)
-                   for f in SoCStatic._fields]),
-        jnp.stack([jnp.asarray(learned, f32),
-                   jnp.asarray(weights.x, f32),
-                   jnp.asarray(weights.y, f32),
-                   jnp.asarray(weights.z, f32)]),
-        jnp.stack([jnp.asarray(getattr(serve_params, f), f32)
-                   for f in type(serve_params)._fields]),
-    ])
-    return _kernel.soc_step_serve(
-        xf, xi, xv, consts, carry0,
-        n_tiles=xs.tiles.shape[-1], n_actions=xs.avail.shape[-1],
-        ddr_attribution=ddr_attribution, faulted=xs.f_exec is not None,
-        interpret=interpret)
+        f32 = jnp.float32
+        xf, xi = pack_inputs(xs)
+        xv = jnp.stack([jnp.asarray(t_arr, f32), jnp.asarray(deadline, f32),
+                        jnp.asarray(priority, f32)], axis=-1)
+        consts = jnp.concatenate([
+            jnp.stack([jnp.asarray(getattr(s, f), f32)
+                       for f in SoCStatic._fields]),
+            jnp.stack([jnp.asarray(learned, f32),
+                       jnp.asarray(weights.x, f32),
+                       jnp.asarray(weights.y, f32),
+                       jnp.asarray(weights.z, f32)]),
+            jnp.stack([jnp.asarray(getattr(serve_params, f), f32)
+                       for f in type(serve_params)._fields]),
+        ])
+        return _kernel.soc_step_serve(
+            xf, xi, xv, consts, carry0,
+            n_tiles=xs.tiles.shape[-1], n_actions=xs.avail.shape[-1],
+            ddr_attribution=ddr_attribution, faulted=xs.f_exec is not None,
+            interpret=interpret)

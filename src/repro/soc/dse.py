@@ -39,7 +39,6 @@ from __future__ import annotations
 
 import dataclasses
 import math
-import time
 from typing import Sequence
 
 import jax
@@ -296,7 +295,6 @@ def run_sweep(samples: Sequence[SampledSoC], *, iters: int = 3,
     seeds = np.asarray([s.seed for s in samples], np.int64)
     env = StackedVecEnv(socs)
 
-    t0 = time.perf_counter()
     train_apps = [make_application(c, seed=s.seed, n_phases=n_phases)
                   for c, s in zip(socs, samples)]
     eval_apps = [make_application(c, seed=s.seed + 1, n_phases=n_phases)
@@ -309,7 +307,6 @@ def run_sweep(samples: Sequence[SampledSoC], *, iters: int = 3,
     lengths = [c.n_steps for c in compiled_iters[0]]
     groups = length_buckets(lengths, max_buckets=max_buckets,
                             min_gain=min_gain)
-    t_compile = time.perf_counter() - t0
 
     def volume(lens, gs):
         return sum(len(g) * max(lens[i] for i in g) for g in gs)
@@ -322,7 +319,6 @@ def run_sweep(samples: Sequence[SampledSoC], *, iters: int = 3,
     real = iters * sum(lengths) + sum(eval_lengths)
 
     parts, subs = [], []
-    t0 = time.perf_counter()
     for g in groups:
         sub = env.sublanes(g)
         subs.append(sub)
@@ -334,7 +330,6 @@ def run_sweep(samples: Sequence[SampledSoC], *, iters: int = 3,
                                    seeds[list(g)], iters))
     nt = reassemble_lanes(groups, [p[0] for p in parts])
     nm = reassemble_lanes(groups, [p[1] for p in parts])
-    t_run = time.perf_counter() - t0
 
     fixed_t, fixed_m = nt[:, :_N_FIXED], nm[:, :_N_FIXED]
     coh_t, coh_m = nt[:, -1], nm[:, -1]
@@ -366,12 +361,6 @@ def run_sweep(samples: Sequence[SampledSoC], *, iters: int = 3,
             "padded_waste_single_call": 1.0 - real / vol_single,
             "padded_waste_bucketed": 1.0 - real / vol_bucketed,
             "waste_reduction": (vol_single - vol_bucketed) / vol_single,
-        },
-        "timing": {
-            "compile_s": t_compile,
-            "train_eval_s": t_run,
-            "padded_steps_per_s": vol_bucketed / max(t_run, 1e-9),
-            "real_invocations_per_s": real / max(t_run, 1e-9),
         },
         "axis_ranking": rank_axes(samples, {
             "speedup_vs_noncoh": margins["speedup_vs_noncoh"],

@@ -136,26 +136,29 @@ def sharded_train_batched_stacked(env, stacked_iters, cfg, weights_batch,
                                   force_shard_map: bool = False):
     """``StackedVecEnv.train_batched`` with the B agents split across
     devices (keys are (K, B, 2); every device keeps all K lanes).
-    ``faults`` replicates like in :func:`sharded_train_batched`."""
-    mesh = _use_mesh(mesh, int(keys.shape[1]), force_shard_map)
+    ``faults`` replicates like in :func:`sharded_train_batched`.
+
+    Under ``shard_map`` the method's own host preparation is traced into
+    the program, so this entry point holds the per-call ``cohm.prep``
+    (mesh and argument layout) and ``cohm.launch`` spans itself."""
+    with jax.profiler.TraceAnnotation("cohm.prep"):
+        mesh = _use_mesh(mesh, int(keys.shape[1]), force_shard_map)
+        consts = (env, *stacked_iters, cfg, eval_stacked)
+        if faults is None:
+            args, in_axes = (weights_batch, keys), (0, 1)
+        else:
+            args, in_axes = (weights_batch, keys, faults), (0, 1, None)
+            consts += ("faulted",)
+
+        def run(w, k, *f):
+            return env.train_batched(stacked_iters, cfg, w, k, eval_stacked,
+                                     *f)
+
     if mesh is None:
         return env.train_batched(stacked_iters, cfg, weights_batch, keys,
                                  eval_stacked, faults)
-
-    if faults is None:
-        def run(w, k):
-            return env.train_batched(stacked_iters, cfg, w, k, eval_stacked)
-
-        return _shard_call(run, mesh, (weights_batch, keys), (0, 1), 1,
-                           consts=(env, *stacked_iters, cfg, eval_stacked))
-
-    def run(w, k, f):
-        return env.train_batched(stacked_iters, cfg, w, k, eval_stacked, f)
-
-    return _shard_call(run, mesh, (weights_batch, keys, faults),
-                       (0, 1, None), 1,
-                       consts=(env, *stacked_iters, cfg, eval_stacked,
-                               "faulted"))
+    with jax.profiler.TraceAnnotation("cohm.launch"):
+        return _shard_call(run, mesh, args, in_axes, 1, consts=consts)
 
 
 def sharded_episodes(env, stacked, specs, cfg=None, keys=None, *,

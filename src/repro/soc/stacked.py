@@ -277,7 +277,9 @@ class StackedVecEnv:
     Build with :meth:`from_simulators` to share DES simulators' resolved
     accelerator profiles (the cross-backend comparison protocol), or
     directly from configs.  All public entry points run every lane in a
-    single jitted call.
+    single jitted call; each one's per-call host preparation runs under a
+    ``cohm.prep`` profiler span, and its jit cache lookup and call under
+    ``cohm.launch``.
 
     ``fused_step`` follows :class:`~repro.soc.vecenv.VecEnv`: ``None``
     (default) enables the :mod:`repro.kernels.soc_step` episode lowering —
@@ -438,28 +440,30 @@ class StackedVecEnv:
         ``episodes_manual`` / ``episodes_q`` triple: the Fig. 9
         evaluation is one call for ALL families across ALL SoCs."""
         self.calls["episodes"] += 1
-        cfg = cfg or qlearn.QConfig()
-        K, N = specs.learned.shape
-        if keys is None:
-            keys = self._default_keys(K, N)
-        axes = _cfg_axes(cfg)
-        cache_key = ("episodes_jit", stacked.n_phases, stacked.n_threads,
-                     tuple(axes))
-        if cache_key not in self._cache:
-            ep = self._episode_fn(stacked.n_phases, stacked.n_threads)
-            w = rewards.PAPER_DEFAULT_WEIGHTS
+        with jax.profiler.TraceAnnotation("cohm.prep"):
+            cfg = cfg or qlearn.QConfig()
+            K, N = specs.learned.shape
+            if keys is None:
+                keys = self._default_keys(K, N)
+            axes = _cfg_axes(cfg)
+        with jax.profiler.TraceAnnotation("cohm.launch"):
+            cache_key = ("episodes_jit", stacked.n_phases,
+                         stacked.n_threads, tuple(axes))
+            if cache_key not in self._cache:
+                ep = self._episode_fn(stacked.n_phases, stacked.n_threads)
+                w = rewards.PAPER_DEFAULT_WEIGHTS
 
-            # One FaultSpec perturbs every (lane, policy) episode
-            # identically: in_axes None at both vmap levels.
-            def one(params, sched, cfg_, spec, key, f):
-                _, res = ep(params, sched, spec, cfg_, w, key, f)
-                return res
+                # One FaultSpec perturbs every (lane, policy) episode
+                # identically: in_axes None at both vmap levels.
+                def one(params, sched, cfg_, spec, key, f):
+                    _, res = ep(params, sched, spec, cfg_, w, key, f)
+                    return res
 
-            self._cache[cache_key] = jax.jit(jax.vmap(
-                jax.vmap(one, in_axes=(None, None, None, 0, 0, None)),
-                in_axes=(0, 0, axes, 0, 0, None)))
-        return self._cache[cache_key](self.params, stacked.schedule, cfg,
-                                      specs, keys, faults)
+                self._cache[cache_key] = jax.jit(jax.vmap(
+                    jax.vmap(one, in_axes=(None, None, None, 0, 0, None)),
+                    in_axes=(0, 0, axes, 0, 0, None)))
+            return self._cache[cache_key](self.params, stacked.schedule,
+                                          cfg, specs, keys, faults)
 
     def baseline(self, stacked: StackedApps,
                  faults=None) -> vec.EpisodeResult:
@@ -486,31 +490,33 @@ class StackedVecEnv:
         invoked).  Returns ``(carry, qstate, ServeResult)`` with
         ``(K, N, ...)`` leaves."""
         self.calls["serve"] += 1
-        cfg = cfg or qlearn.QConfig()
-        K, N = specs.learned.shape
-        if keys is None:
-            keys = self._default_keys(K, N)
-        axes = _cfg_axes(cfg)
-        cache_key = ("serve_jit", stacked.n_phases, stacked.n_threads,
-                     queue_cap, n_requests, tuple(axes))
-        if cache_key not in self._cache:
-            base = vec.build_serve_fn(n_requests, queue_cap,
-                                      fused=self.fused_step)
-            w = rewards.PAPER_DEFAULT_WEIGHTS
-            t0 = jnp.zeros((), jnp.float32)
+        with jax.profiler.TraceAnnotation("cohm.prep"):
+            cfg = cfg or qlearn.QConfig()
+            K, N = specs.learned.shape
+            if keys is None:
+                keys = self._default_keys(K, N)
+            axes = _cfg_axes(cfg)
+            n_real = jnp.asarray(stacked.n_steps, jnp.int32)
+        with jax.profiler.TraceAnnotation("cohm.launch"):
+            cache_key = ("serve_jit", stacked.n_phases, stacked.n_threads,
+                         queue_cap, n_requests, tuple(axes))
+            if cache_key not in self._cache:
+                base = vec.build_serve_fn(n_requests, queue_cap,
+                                          fused=self.fused_step)
+                w = rewards.PAPER_DEFAULT_WEIGHTS
+                t0 = jnp.zeros((), jnp.float32)
 
-            def one(params, sched, n_real, cfg_, spec, tspec, key, f):
-                return base(params, sched, spec, cfg_, w, tspec, None,
-                            key, t0, f, n_real)
+                def one(params, sched, n_real, cfg_, spec, tspec, key, f):
+                    return base(params, sched, spec, cfg_, w, tspec, None,
+                                key, t0, f, n_real)
 
-            self._cache[cache_key] = jax.jit(jax.vmap(
-                jax.vmap(one, in_axes=(None, None, None, None, 0, None,
-                                       0, None)),
-                in_axes=(0, 0, 0, axes, 0, None, 0, None)))
-        n_real = jnp.asarray(stacked.n_steps, jnp.int32)
-        return self._cache[cache_key](self.params, stacked.schedule,
-                                      n_real, cfg, specs, traffic, keys,
-                                      faults)
+                self._cache[cache_key] = jax.jit(jax.vmap(
+                    jax.vmap(one, in_axes=(None, None, None, None, 0, None,
+                                           0, None)),
+                    in_axes=(0, 0, 0, axes, 0, None, 0, None)))
+            return self._cache[cache_key](self.params, stacked.schedule,
+                                          n_real, cfg, specs, traffic, keys,
+                                          faults)
 
     # ------------------------------------------------------------ training
     def train_batched(self, stacked_iters: Sequence[StackedApps],
@@ -531,50 +537,54 @@ class StackedVecEnv:
         (norm_time, norm_mem) histories of shape (K, B, iterations)."""
         self.calls["train"] += 1
         first = stacked_iters[0]
-        scheds = jax.tree_util.tree_map(
-            lambda *xs: jnp.stack(xs, axis=1),
-            *[st.schedule for st in stacked_iters])
         eval_shape = (None if eval_stacked is None
                       else (eval_stacked.n_phases, eval_stacked.n_threads))
-        if eval_stacked is not None:
-            eval_sched = eval_stacked.schedule
-            base = self.baseline(eval_stacked, faults=faults)
-            pmask = eval_stacked.phase_mask
-            eval_axes = (0, 0, 0)
-        else:
-            eval_sched = base = pmask = None
-            eval_axes = (None, None, None)
+        with jax.profiler.TraceAnnotation("cohm.prep"):
+            scheds = jax.tree_util.tree_map(
+                lambda *xs: jnp.stack(xs, axis=1),
+                *[st.schedule for st in stacked_iters])
+            if eval_stacked is not None:
+                eval_sched = eval_stacked.schedule
+                base = self.baseline(eval_stacked, faults=faults)
+                pmask = eval_stacked.phase_mask
+                eval_axes = (0, 0, 0)
+            else:
+                eval_sched = base = pmask = None
+                eval_axes = (None, None, None)
 
-        B = keys.shape[1]
-        q0 = jax.tree_util.tree_map(
-            lambda x: jnp.broadcast_to(x, (self.n_lanes,) + x.shape),
-            qlearn.init_qstate_batch(qlearn.QConfig(), B))
-        axes = _cfg_axes(cfg)
-        carry_axes = vec.TrainCarry(key=0, it=None, best=0)
-        cache_key = ("train_jit", first.n_phases, first.n_threads,
-                     eval_shape, tuple(axes))
-        if cache_key not in self._cache:
-            train_one = vec.build_train_fn(
-                first.n_phases, first.n_threads, eval_shape,
-                self.cycle_time, demand_cache=True, gated=True,
-                fused=self.fused_step)
-            # Carry batches (key, best) per agent / per lane; the
-            # iteration counter and the FaultSpec replicate everywhere.
-            agents = jax.vmap(train_one,
-                              in_axes=(None, None, None, None, None, None,
-                                       rewards.RewardWeights(0, 0, 0),
-                                       carry_axes, 0, None),
-                              out_axes=(0, carry_axes, 0))
-            self._cache[cache_key] = jax.jit(jax.vmap(
-                agents,
-                in_axes=(0, 0, *eval_axes, axes, None, carry_axes, 0, None),
-                out_axes=(0, carry_axes, 0)))
-        carry0 = vec.TrainCarry(
-            key=jnp.asarray(keys), it=jnp.zeros((), jnp.int32),
-            best=jnp.full(keys.shape[:2], -jnp.inf, jnp.float32))
-        qs, _, hist = self._cache[cache_key](
-            self.params, scheds, eval_sched, base, pmask, cfg,
-            weights_batch, carry0, q0, faults)
+            B = keys.shape[1]
+            q0 = jax.tree_util.tree_map(
+                lambda x: jnp.broadcast_to(x, (self.n_lanes,) + x.shape),
+                qlearn.init_qstate_batch(qlearn.QConfig(), B))
+            axes = _cfg_axes(cfg)
+            carry_axes = vec.TrainCarry(key=0, it=None, best=0)
+            carry0 = vec.TrainCarry(
+                key=jnp.asarray(keys), it=jnp.zeros((), jnp.int32),
+                best=jnp.full(keys.shape[:2], -jnp.inf, jnp.float32))
+        with jax.profiler.TraceAnnotation("cohm.launch"):
+            cache_key = ("train_jit", first.n_phases, first.n_threads,
+                         eval_shape, tuple(axes))
+            if cache_key not in self._cache:
+                train_one = vec.build_train_fn(
+                    first.n_phases, first.n_threads, eval_shape,
+                    self.cycle_time, demand_cache=True, gated=True,
+                    fused=self.fused_step)
+                # Carry batches (key, best) per agent / per lane; the
+                # iteration counter and the FaultSpec replicate everywhere.
+                agents = jax.vmap(train_one,
+                                  in_axes=(None, None, None, None, None,
+                                           None,
+                                           rewards.RewardWeights(0, 0, 0),
+                                           carry_axes, 0, None),
+                                  out_axes=(0, carry_axes, 0))
+                self._cache[cache_key] = jax.jit(jax.vmap(
+                    agents,
+                    in_axes=(0, 0, *eval_axes, axes, None, carry_axes, 0,
+                             None),
+                    out_axes=(0, carry_axes, 0)))
+            qs, _, hist = self._cache[cache_key](
+                self.params, scheds, eval_sched, base, pmask, cfg,
+                weights_batch, carry0, q0, faults)
         return qs, hist
 
     def evaluate_batched(self, stacked: StackedApps, qstates: qlearn.QState,

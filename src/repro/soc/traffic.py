@@ -144,8 +144,10 @@ def bursty(rate, *, burst_rate=4.0, p_burst=0.05, p_calm=0.25,
 def chunk_key(spec: TrafficSpec, chunk: int) -> TrafficSpec:
     """The spec for chunk ``chunk`` of a long-lived stream: same contract,
     chunk-folded key — every chunk draws fresh arrivals deterministically
-    (``fold_in``, the FaultSpec per-iteration protocol)."""
-    return spec._replace(key=jax.random.fold_in(spec.key, chunk))
+    (``fold_in``, the FaultSpec per-iteration protocol).  Host preparation
+    of a serving chunk, so it runs under the ``cohm.prep`` span."""
+    with jax.profiler.TraceAnnotation("cohm.prep"):
+        return spec._replace(key=jax.random.fold_in(spec.key, chunk))
 
 
 class Arrivals(NamedTuple):

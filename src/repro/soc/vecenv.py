@@ -667,20 +667,22 @@ def build_episode_fn(n_phases: int, n_threads: int,
         # q-kind episode bit for bit; non-learned specs discard the
         # selection, so their results are key-independent.
         n_steps = sched.acc_id.shape[0]
-        if presample_noise:
-            noise = qlearn.sample_select_noise(
-                key, (n_steps,), masks.shape[-1])
-        else:
-            noise = qlearn.SelectNoise(
-                u_explore=jnp.zeros((n_steps,), jnp.float32),
-                g_pick=jnp.zeros((n_steps, 0), jnp.float32),
-                g_tie=jnp.zeros((n_steps, 0), jnp.float32))
-        # Per-step fault rows are pre-sampled from the spec's OWN key
-        # (soc.faults), so the episode's main key stream is untouched and
-        # ``faults=None`` stays bitwise-identical to today's path (None
-        # scans as an empty pytree — the step sees fr is None).
-        frows = (None if faults is None
-                 else fault_mod.sample_fault_arrays(faults, sched.acc_id))
+        with jax.named_scope("cohm_presample"):
+            if presample_noise:
+                noise = qlearn.sample_select_noise(
+                    key, (n_steps,), masks.shape[-1])
+            else:
+                noise = qlearn.SelectNoise(
+                    u_explore=jnp.zeros((n_steps,), jnp.float32),
+                    g_pick=jnp.zeros((n_steps, 0), jnp.float32),
+                    g_tie=jnp.zeros((n_steps, 0), jnp.float32))
+            # Per-step fault rows are pre-sampled from the spec's OWN key
+            # (soc.faults), so the episode's main key stream is untouched
+            # and ``faults=None`` stays bitwise-identical to today's path
+            # (None scans as an empty pytree — the step sees fr is None).
+            frows = (None if faults is None
+                     else fault_mod.sample_fault_arrays(faults,
+                                                        sched.acc_id))
         rs0 = rewards.init_reward_state(n_accs)
         if mlp is not None:
             carry = (qs0, rs0, tbl0, mlp.wpack,
@@ -732,6 +734,8 @@ def _build_fused_episode_fn(n_phases: int, n_threads: int,
     pregather, visits/step replay, and the per-phase metric tail (shared
     verbatim with the unfused episode).  Imported lazily to keep
     ``soc.vecenv`` importable without the kernels package on odd installs.
+    The pre-sampling runs under the ``cohm_presample`` name scope, the
+    step under ``cohm_step``.
     """
     from repro.kernels.soc_step import ops as soc_step_ops
     from repro.kernels.soc_step.ref import StepInputs
@@ -746,32 +750,37 @@ def _build_fused_episode_fn(n_phases: int, n_threads: int,
         n_accs = pmat.shape[0]
         n_steps = sched.acc_id.shape[0]
 
-        # Same one-call noise protocol as the unfused episode — identical
-        # key consumption, so fused and unfused draw identical variates.
-        noise = qlearn.sample_select_noise(key, (n_steps,), masks.shape[-1])
-        # Counter increments the in-scan update would apply: zero on frozen
-        # agents and (gated schedules) on padding rows.  MLP-treedef specs
-        # precompute the MERGED schedule — the live agent's (step0, frozen)
-        # drive the decay, and the increments are split afterwards so each
-        # family's counter only advances when it drove the episode.  With
-        # qfun=False (placeholder MLP) the merge selects the table's
-        # values, so eps_t/alpha_t/inc are bitwise the tabular ones.
-        live = sched.valid if gated else jnp.ones_like(sched.valid)
-        if mlp is None:
-            step0_eff, frozen_eff = qs0.step, qs0.frozen
-        else:
-            step0_eff = jnp.where(spec.qfun, mlp.step, qs0.step)
-            frozen_eff = jnp.where(spec.qfun, mlp.frozen, qs0.frozen)
-        inc = (live & ~frozen_eff).astype(jnp.int32)
-        eps_t, alpha_t = qlearn.decay_arrays(cfg, step0_eff, frozen_eff,
-                                             inc)
-        # Fault rows ride four trailing xs columns (same pre-sampled draw
-        # as the unfused scan, so the lowerings stay bitwise-equal).
-        frow = {}
-        if faults is not None:
-            fr = fault_mod.sample_fault_arrays(faults, sched.acc_id)
-            frow = dict(f_exec=fr.exec_scale, f_ddr=fr.ddr_scale,
-                        f_llc=fr.llc_extra, f_retry=fr.retry_cycles)
+        with jax.named_scope("cohm_presample"):
+            # Same one-call noise protocol as the unfused episode —
+            # identical key consumption, so fused and unfused draw
+            # identical variates.
+            noise = qlearn.sample_select_noise(key, (n_steps,),
+                                               masks.shape[-1])
+            # Counter increments the in-scan update would apply: zero on
+            # frozen agents and (gated schedules) on padding rows.
+            # MLP-treedef specs precompute the MERGED schedule — the live
+            # agent's (step0, frozen) drive the decay, and the increments
+            # are split afterwards so each family's counter only advances
+            # when it drove the episode.  With qfun=False (placeholder
+            # MLP) the merge selects the table's values, so
+            # eps_t/alpha_t/inc are bitwise the tabular ones.
+            live = sched.valid if gated else jnp.ones_like(sched.valid)
+            if mlp is None:
+                step0_eff, frozen_eff = qs0.step, qs0.frozen
+            else:
+                step0_eff = jnp.where(spec.qfun, mlp.step, qs0.step)
+                frozen_eff = jnp.where(spec.qfun, mlp.frozen, qs0.frozen)
+            inc = (live & ~frozen_eff).astype(jnp.int32)
+            eps_t, alpha_t = qlearn.decay_arrays(cfg, step0_eff,
+                                                 frozen_eff, inc)
+            # Fault rows ride four trailing xs columns (same pre-sampled
+            # draw as the unfused scan, so the lowerings stay
+            # bitwise-equal).
+            frow = {}
+            if faults is not None:
+                fr = fault_mod.sample_fault_arrays(faults, sched.acc_id)
+                frow = dict(f_exec=fr.exec_scale, f_ddr=fr.ddr_scale,
+                            f_llc=fr.llc_extra, f_retry=fr.retry_cycles)
         xs = StepInputs(
             acc_id=sched.acc_id, footprint=sched.footprint,
             tiles=sched.tiles, thread=sched.thread, fresh=sched.fresh,
@@ -1392,7 +1401,8 @@ def build_serve_fn(n_requests: int, queue_cap: int,
     through the same step — their trained weights ride ``carry.wpack``
     (rebuild the agent with ``mlp._replace(wpack=carry.wpack,
     step=carry.step)``); the returned placeholder ``qstate`` stays
-    frozen and untouched.
+    frozen and untouched.  Arrival, noise and fault pre-sampling run
+    under the ``cohm_presample`` name scope.
     """
     from repro.kernels.soc_step import ops as soc_step_ops
     from repro.kernels.soc_step.ref import (SERVE_YCOLS, ServeParams,
@@ -1410,19 +1420,21 @@ def build_serve_fn(n_requests: int, queue_cap: int,
         n_rows = sched.acc_id.shape[0] if n_real is None else n_real
         qs0 = spec.qstate
         mlp = spec.mlp
-        arr = traffic_mod.sample_arrivals(tspec, n_requests, n_rows, t0)
-        acc = sched.acc_id[arr.row]
+        with jax.named_scope("cohm_presample"):
+            arr = traffic_mod.sample_arrivals(tspec, n_requests, n_rows, t0)
+            acc = sched.acc_id[arr.row]
 
-        # Same one-call select-noise protocol as the episodes; faults are
-        # pre-sampled against the *request* accelerator stream, so a storm
-        # during a load spike composes with admission per-request.
-        noise = qlearn.sample_select_noise(key, (n_requests,),
-                                           masks.shape[-1])
-        frow = {}
-        if faults is not None:
-            fr = fault_mod.sample_fault_arrays(faults, acc)
-            frow = dict(f_exec=fr.exec_scale, f_ddr=fr.ddr_scale,
-                        f_llc=fr.llc_extra, f_retry=fr.retry_cycles)
+            # Same one-call select-noise protocol as the episodes; faults
+            # are pre-sampled against the *request* accelerator stream, so
+            # a storm during a load spike composes with admission
+            # per-request.
+            noise = qlearn.sample_select_noise(key, (n_requests,),
+                                               masks.shape[-1])
+            frow = {}
+            if faults is not None:
+                fr = fault_mod.sample_fault_arrays(faults, acc)
+                frow = dict(f_exec=fr.exec_scale, f_ddr=fr.ddr_scale,
+                            f_llc=fr.llc_extra, f_retry=fr.retry_cycles)
         # thread/fresh/others/valid/eps/alpha are serve-step-owned
         # placeholders (see serve_step): serving concurrency is between
         # accelerators, and the decay schedule evaluates in-carry because
@@ -1588,12 +1600,15 @@ class ServeEnv:
             return self.env.episode_spec(compiled, spec, cfg=cfg,
                                          weights=weights, key=key,
                                          faults=faults)
-        cfg = cfg or qlearn.QConfig()
-        weights = weights or rewards.PAPER_DEFAULT_WEIGHTS
-        key = key if key is not None else jax.random.PRNGKey(0)
-        fn, _ = self._serve_fn(int(n_requests or self.n_requests))
-        return fn(compiled.schedule, spec, cfg, weights, traffic, carry,
-                  key, jnp.asarray(t0, jnp.float32), faults)
+        with jax.profiler.TraceAnnotation("cohm.prep"):
+            cfg = cfg or qlearn.QConfig()
+            weights = weights or rewards.PAPER_DEFAULT_WEIGHTS
+            key = key if key is not None else jax.random.PRNGKey(0)
+            t0 = jnp.asarray(t0, jnp.float32)
+        with jax.profiler.TraceAnnotation("cohm.launch"):
+            fn, _ = self._serve_fn(int(n_requests or self.n_requests))
+            return fn(compiled.schedule, spec, cfg, weights, traffic, carry,
+                      key, t0, faults)
 
     def serve_specs(self, compiled: CompiledApp, specs: PolicySpec,
                     traffic: traffic_mod.TrafficSpec, *,
