@@ -4,8 +4,9 @@
     python chip_smoke.py --chips 4    # four chips: the lane-sharded trainer
 
 Each phase drives the library's own entry points at the sizes the figures
-run, through the default dispatch (the Pallas ``soc_step`` kernels on a
-TPU), and then makes the same call on an environment built with
+run, through the default dispatch (the fused XLA scan for the batched
+training and evaluation calls, the Pallas serve kernel for serving), and
+then makes the same call on an environment built with
 ``fused_step=False`` (the unfused XLA scan) on the same chip:
 
 * train: ``StackedVecEnv.train_batched`` over Fig. 9's eight (SoC,
@@ -48,10 +49,10 @@ import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
 
-REL_TOL = 1e-4       # deterministic families, kernel vs unfused scan
+REL_TOL = 1e-4       # deterministic families, fused vs unfused step
 SHARD_ATOL = 1e-6    # float leaves, four chips vs one
-QTABLE_ATOL = 1e-5   # trained Q-tables, kernel vs unfused scan
-MIN_MATCH = 0.99     # learned agents' mode-match share, kernel vs scan
+QTABLE_ATOL = 1e-5   # trained Q-tables, fused vs unfused step
+MIN_MATCH = 0.99     # learned agents' mode-match share, fused vs unfused
 N_PHASES = 8         # the full Fig. 9 application length
 ITERS = 3
 SEEDS = 4
@@ -69,7 +70,7 @@ def _timed(fn):
 
 
 def _report_times(phase, ker, ref):
-    print(f"{phase} times: kernel setup_s={ker[0]:.3f} wall_s={ker[1]:.3f} "
+    print(f"{phase} times: fused setup_s={ker[0]:.3f} wall_s={ker[1]:.3f} "
           f"ref setup_s={ref[0]:.3f} wall_s={ref[1]:.3f}")
 
 

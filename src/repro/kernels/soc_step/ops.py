@@ -18,6 +18,12 @@ this kernel's sequential grid only pays off where VMEM scratch is real:
     auto-enables the interpreter on CPU, which is how the kernel-vs-ref
     tests execute the kernel body without a TPU.
 
+The batched entry points choose the lowering from the size of the call
+(:func:`episode_kernel`): the kernel's grid walks a vmapped batch's
+episodes one after another, the scan steps them side by side, so a call
+of :data:`SCAN_MIN_EPISODES` or more episodes takes the scan on every
+platform.
+
 Both lowerings share :func:`~repro.kernels.soc_step.ref.fused_step` and
 the :func:`~repro.kernels.soc_step.ref.pack_inputs` row layout, so they
 agree to float tolerance by construction (bitwise on CPU).  Both entry
@@ -35,8 +41,20 @@ from repro.kernels.soc_step.ref import (StepInputs, episode_ref,
                                         pack_inputs, unpack_ys)
 
 
+# Episodes per call from which the XLA scan beats the Pallas kernel on a
+# TPU v5e (crossover table in PERF.md section 6).
+SCAN_MIN_EPISODES = 8
+
+
 def _on_cpu() -> bool:
     return jax.default_backend() == "cpu"
+
+
+def episode_kernel(n_episodes: int) -> bool:
+    """Whether a call running ``n_episodes`` episodes at once lowers them
+    through the Pallas kernel: below :data:`SCAN_MIN_EPISODES` on an
+    accelerator, never on the CPU."""
+    return n_episodes < SCAN_MIN_EPISODES and not _on_cpu()
 
 
 def fused_episode(s: SoCStatic, learned, weights, qtable0, extrema0,

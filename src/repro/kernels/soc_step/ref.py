@@ -34,7 +34,8 @@ bitwise equality on CPU.
 :func:`episode_ref` scans :func:`fused_step` over a whole episode — it is
 both the oracle ``tests/test_kernels.py`` checks the Pallas kernel
 against and the fast XLA lowering :mod:`repro.kernels.soc_step.ops`
-dispatches to on CPU backends.
+dispatches to on CPU backends and, on every backend, for calls that
+batch many episodes.
 """
 from __future__ import annotations
 
@@ -610,6 +611,29 @@ def derive_geom(s: SoCStatic) -> tuple[CacheGeometry, jnp.ndarray]:
     return geom, warm_cap
 
 
+@jax.custom_batching.custom_vmap
+def _per_episode(carry, ref):
+    """``carry`` itself.  Under ``vmap`` over episodes (``ref`` batched) its
+    unbatched leaves are broadcast over the batch, so a scan entered with
+    it has its carry batched from the start: the scan's batching rule then
+    batches the step body once, where a constant initial slot table or
+    reward extrema would make it batch the body a second time at its
+    fixpoint."""
+    return carry
+
+
+@_per_episode.def_vmap
+def _per_episode_vmap(axis_size, in_batched, carry, ref):
+    carry_batched, ref_batched = in_batched
+    if not any(jax.tree_util.tree_leaves(ref_batched)):
+        return _per_episode(carry, ref), carry_batched
+    carry = jax.tree_util.tree_map(
+        lambda x, b: x if b else jnp.broadcast_to(x, (axis_size,) + x.shape),
+        carry, carry_batched)
+    return (_per_episode(carry, ref),
+            jax.tree_util.tree_map(lambda _: True, carry_batched))
+
+
 def episode_ref(s: SoCStatic, learned, weights, qtable0, extrema0,
                 xs: StepInputs, *, ddr_attribution: bool = False,
                 gated: bool = False, wpack0=None, qfun=None, mlp_lr=None,
@@ -620,6 +644,8 @@ def episode_ref(s: SoCStatic, learned, weights, qtable0, extrema0,
     reward-extrema table ((4, n_accs), from ``rewards.init_reward_state``).
     Returns ``(qtable_final, ys)`` with ``ys`` the per-step
     ``(mode, state_idx, action, exec_cycles, offchip, reward)`` arrays.
+    Under ``vmap`` over episodes the carry enters the scan batched
+    (:func:`_per_episode`).
 
     With a packed MLP (``wpack0`` + the traced ``qfun`` flag,
     :mod:`repro.soc.nn`) the weights ride the scan carry next to the
@@ -639,7 +665,8 @@ def episode_ref(s: SoCStatic, learned, weights, qtable0, extrema0,
                 ddr_attribution=ddr_attribution, gated=gated)
             return (qtable, rs, tbl), y
 
-        (qtable, _, _), y = jax.lax.scan(step, (qtable0, rs0, tbl0), xs)
+        (qtable, _, _), y = jax.lax.scan(
+            step, _per_episode((qtable0, rs0, tbl0), xs.u_explore), xs)
         return qtable, unpack_ys(y)
 
     def step_mlp(carry, x):
@@ -652,5 +679,6 @@ def episode_ref(s: SoCStatic, learned, weights, qtable0, extrema0,
         return (qtable, rs, tbl, wpack), y
 
     (qtable, _, _, wpack), y = jax.lax.scan(
-        step_mlp, (qtable0, rs0, tbl0, wpack0), xs)
+        step_mlp, _per_episode((qtable0, rs0, tbl0, wpack0), xs.u_explore),
+        xs)
     return qtable, wpack, unpack_ys(y)

@@ -271,6 +271,62 @@ def _cfg_axes(cfg: qlearn.QConfig):
         for v in cfg])
 
 
+# Rows of a flattened episode batch that fill whole lane tiles of a TPU
+# vector register: only such a batch ran the step scan faster flat than
+# under nested vmaps on a v5e (PERF.md section 6).
+FLAT_GRID_ROWS = 128
+
+
+def _vmap_grid(fn, lane_axes, item_axes, out_axes):
+    """``vmap(vmap(fn, item_axes), lane_axes)``, as ONE ``vmap`` over the
+    flattened (K lanes x N items) grid where K*N fills whole
+    :data:`FLAT_GRID_ROWS` tiles.
+
+    ``lane_axes`` and ``item_axes`` are ``vmap`` in_axes prefixes of 0 or
+    None; ``out_axes`` (0 or None) holds for both levels.  Flat, a leaf
+    batched over only one of the two axes is broadcast over the other,
+    every batched leaf is flattened to a leading K*N axis and batched
+    outputs come back as (K, N, ...).  One level also batches a scan in
+    ``fn`` once, where nested vmaps batch it again at the outer level,
+    over a body already batched."""
+    nested = jax.vmap(jax.vmap(fn, in_axes=item_axes, out_axes=out_axes),
+                      in_axes=lane_axes, out_axes=out_axes)
+
+    def marked(axes, tree):
+        # None axes as -1, so broadcasting the prefix keeps them as leaves.
+        axes = jax.tree_util.tree_map(lambda a: -1 if a is None else a,
+                                      axes, is_leaf=lambda a: a is None)
+        return jax.tree.broadcast(axes, tree)
+
+    def run(*args):
+        lane, item = marked(lane_axes, args), marked(item_axes, args)
+        leaves = list(zip(*(jax.tree_util.tree_leaves(t)
+                            for t in (args, lane, item))))
+        k = next(x.shape[0] for x, l, _ in leaves if l == 0)
+        n = next(x.shape[l + 1] for x, l, i in leaves if i == 0)
+        if (k * n) % FLAT_GRID_ROWS:
+            return nested(*args)
+
+        def flat(x, l, i):
+            if l == i == -1:
+                return x
+            if l == -1:
+                x = jnp.broadcast_to(x, (k,) + x.shape)
+            elif i == -1:
+                x = jnp.broadcast_to(x[:, None], (k, n) + x.shape[1:])
+            return x.reshape((k * n,) + x.shape[2:])
+
+        out = jax.vmap(
+            fn, in_axes=jax.tree.map(lambda l, i: None if l == i == -1
+                                     else 0, lane, item),
+            out_axes=out_axes)(*jax.tree.map(flat, args, lane, item))
+        return jax.tree.map(
+            lambda x, o: x if o == -1 else x.reshape((k, n) + x.shape[1:]),
+            out, marked(out_axes, out))
+
+    return run
+
+
 class StackedVecEnv:
     """K SoCs as one vmapped environment (always the carry-cached step).
 
@@ -284,7 +340,9 @@ class StackedVecEnv:
     ``fused_step`` follows :class:`~repro.soc.vecenv.VecEnv`: ``None``
     (default) enables the :mod:`repro.kernels.soc_step` episode lowering —
     the stacked path always runs the fast (demand-cached, presampled)
-    step, so only equivalence tests pass ``False``.
+    step, so only equivalence tests pass ``False``.  Training and episode
+    calls lower that step by how many episodes they run, lanes times
+    agents or policies (:func:`~repro.soc.vecenv.episode_lowering`).
     """
 
     def __init__(self, socs: Sequence[SoCConfig], seed: int = 0,
@@ -320,7 +378,9 @@ class StackedVecEnv:
                                      static=static)
         self._cache: dict = {}
         # Jitted-call accounting: fig9's acceptance protocol asserts the
-        # whole figure is one train + one eval call in --quick mode.
+        # whole figure is one train + one eval call in --quick mode; each
+        # train or episodes call also counts its step lowering
+        # (``episode_kernel`` / ``episode_scan``).
         self.calls = collections.Counter()
 
     @classmethod
@@ -347,12 +407,14 @@ class StackedVecEnv:
         return compile_apps_stacked(apps, self.socs, seed)
 
     # ------------------------------------------------------------ episodes
-    def _episode_fn(self, n_phases: int, n_threads: int):
-        key = ("ep", n_phases, n_threads)
+    def _episode_fn(self, n_phases: int, n_threads: int,
+                    kernel: bool | None):
+        key = ("ep", n_phases, n_threads, kernel)
         if key not in self._cache:
             self._cache[key] = vec.build_episode_fn(
                 n_phases, n_threads, self.cycle_time,
-                demand_cache=True, gated=True, fused=self.fused_step)
+                demand_cache=True, gated=True, fused=self.fused_step,
+                kernel=kernel)
         return self._cache[key]
 
     def _default_keys(self, *batch) -> jnp.ndarray:
@@ -446,11 +508,13 @@ class StackedVecEnv:
             if keys is None:
                 keys = self._default_keys(K, N)
             axes = _cfg_axes(cfg)
+            kernel = vec.episode_lowering(self.calls, K * N, self.fused_step)
         with jax.profiler.TraceAnnotation("cohm.launch"):
             cache_key = ("episodes_jit", stacked.n_phases,
-                         stacked.n_threads, tuple(axes))
+                         stacked.n_threads, tuple(axes), kernel)
             if cache_key not in self._cache:
-                ep = self._episode_fn(stacked.n_phases, stacked.n_threads)
+                ep = self._episode_fn(stacked.n_phases, stacked.n_threads,
+                                      kernel)
                 w = rewards.PAPER_DEFAULT_WEIGHTS
 
                 # One FaultSpec perturbs every (lane, policy) episode
@@ -459,9 +523,10 @@ class StackedVecEnv:
                     _, res = ep(params, sched, spec, cfg_, w, key, f)
                     return res
 
-                self._cache[cache_key] = jax.jit(jax.vmap(
-                    jax.vmap(one, in_axes=(None, None, None, 0, 0, None)),
-                    in_axes=(0, 0, axes, 0, 0, None)))
+                self._cache[cache_key] = jax.jit(_vmap_grid(
+                    one, lane_axes=(0, 0, axes, 0, 0, None),
+                    item_axes=(None, None, None, 0, 0, None),
+                    out_axes=0))
             return self._cache[cache_key](self.params, stacked.schedule,
                                           cfg, specs, keys, faults)
 
@@ -553,6 +618,8 @@ class StackedVecEnv:
                 eval_axes = (None, None, None)
 
             B = keys.shape[1]
+            kernel = vec.episode_lowering(self.calls, self.n_lanes * B,
+                                          self.fused_step)
             q0 = jax.tree_util.tree_map(
                 lambda x: jnp.broadcast_to(x, (self.n_lanes,) + x.shape),
                 qlearn.init_qstate_batch(qlearn.QConfig(), B))
@@ -563,24 +630,21 @@ class StackedVecEnv:
                 best=jnp.full(keys.shape[:2], -jnp.inf, jnp.float32))
         with jax.profiler.TraceAnnotation("cohm.launch"):
             cache_key = ("train_jit", first.n_phases, first.n_threads,
-                         eval_shape, tuple(axes))
+                         eval_shape, tuple(axes), kernel)
             if cache_key not in self._cache:
                 train_one = vec.build_train_fn(
                     first.n_phases, first.n_threads, eval_shape,
                     self.cycle_time, demand_cache=True, gated=True,
-                    fused=self.fused_step)
+                    fused=self.fused_step, kernel=kernel)
                 # Carry batches (key, best) per agent / per lane; the
                 # iteration counter and the FaultSpec replicate everywhere.
-                agents = jax.vmap(train_one,
-                                  in_axes=(None, None, None, None, None,
-                                           None,
-                                           rewards.RewardWeights(0, 0, 0),
-                                           carry_axes, 0, None),
-                                  out_axes=(0, carry_axes, 0))
-                self._cache[cache_key] = jax.jit(jax.vmap(
-                    agents,
-                    in_axes=(0, 0, *eval_axes, axes, None, carry_axes, 0,
-                             None),
+                self._cache[cache_key] = jax.jit(_vmap_grid(
+                    train_one,
+                    lane_axes=(0, 0, *eval_axes, axes, None, carry_axes, 0,
+                               None),
+                    item_axes=(None, None, None, None, None, None,
+                               rewards.RewardWeights(0, 0, 0), carry_axes,
+                               0, None),
                     out_axes=(0, carry_axes, 0)))
             qs, _, hist = self._cache[cache_key](
                 self.params, scheds, eval_sched, base, pmask, cfg,

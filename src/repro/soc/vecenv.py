@@ -55,6 +55,7 @@ concurrency set and the wall clock are exactly the DES's, which is what
 """
 from __future__ import annotations
 
+import collections
 import dataclasses
 from typing import NamedTuple, Sequence
 
@@ -399,7 +400,8 @@ def build_episode_fn(n_phases: int, n_threads: int,
                      cycle_time: float, demand_cache: bool = True,
                      gated: bool = False, presample_noise: bool = True,
                      ddr_attribution: bool = False,
-                     fused: bool = False, debug_finite: bool = False):
+                     fused: bool = False, debug_finite: bool = False,
+                     kernel: bool | None = None):
     """Build THE jit-compatible episode function for a schedule geometry.
 
     There is one episode; policies differ only in the :class:`PolicySpec`
@@ -432,7 +434,10 @@ def build_episode_fn(n_phases: int, n_threads: int,
     pregathered into the xs — a Pallas kernel on accelerator backends, a
     single tight XLA scan on CPU.  Results are bitwise-identical to the
     unfused reference step (pinned by the equivalence tests); it requires
-    the ``demand_cache`` + ``presample_noise`` fast path.
+    the ``demand_cache`` + ``presample_noise`` fast path.  ``kernel`` is
+    :func:`repro.kernels.soc_step.ops.fused_episode`'s lowering choice
+    (``None``: the platform's); batched callers take it from
+    :func:`episode_lowering`.
 
     The episode closure takes an optional trailing :class:`~repro.soc.
     faults.FaultSpec` — pre-sampled per-step perturbation rows join the
@@ -449,7 +454,8 @@ def build_episode_fn(n_phases: int, n_threads: int,
             "fused_step requires demand_cache=True and presample_noise=True")
     if fused:
         return _build_fused_episode_fn(n_phases, n_threads, cycle_time,
-                                       gated, ddr_attribution, debug_finite)
+                                       gated, ddr_attribution, debug_finite,
+                                       kernel)
     T, P = n_threads, n_phases
 
     def episode(params: LaneParams, sched: Schedule, spec: PolicySpec, cfg,
@@ -726,7 +732,8 @@ def build_episode_fn(n_phases: int, n_threads: int,
 def _build_fused_episode_fn(n_phases: int, n_threads: int,
                             cycle_time: float, gated: bool,
                             ddr_attribution: bool,
-                            debug_finite: bool = False):
+                            debug_finite: bool = False,
+                            kernel: bool | None = None):
     """The fused-step lowering of :func:`build_episode_fn` (its ``fused``
     paragraph documents the semantics).  The step itself lives in
     :mod:`repro.kernels.soc_step`; this closure owns the episode-level
@@ -792,14 +799,14 @@ def _build_fused_episode_fn(n_phases: int, n_threads: int,
             qtable, ys = soc_step_ops.fused_episode(
                 s, spec.learned, weights, qs0.qtable,
                 rewards.init_reward_state(n_accs).extrema, xs,
-                ddr_attribution=ddr_attribution, gated=gated)
+                ddr_attribution=ddr_attribution, gated=gated, kernel=kernel)
             inc_tbl = inc
         else:
             qtable, wpack, ys = soc_step_ops.fused_episode(
                 s, spec.learned, weights, qs0.qtable,
                 rewards.init_reward_state(n_accs).extrema, xs,
                 ddr_attribution=ddr_attribution, gated=gated,
-                qfun=spec.qfun, mlp=mlp)
+                qfun=spec.qfun, mlp=mlp, kernel=kernel)
             inc_tbl = jnp.where(spec.qfun, 0, inc)
         mode, state_idx, action, exec_c, off, rew = ys
         qs_final = qlearn.replay_visits(qs0, qtable, state_idx, action,
@@ -861,7 +868,7 @@ def build_train_fn(n_phases: int, n_threads: int, eval_shape,
                    cycle_time: float, demand_cache: bool = True,
                    gated: bool = False, presample_noise: bool = True,
                    ddr_attribution: bool = False, fused: bool = False,
-                   debug_finite: bool = False):
+                   debug_finite: bool = False, kernel: bool | None = None):
     """Build ``train_one(params, train_scheds, eval_sched, base, phase_mask,
     cfg, weights, carry0, q0, faults)``: a scan of training episodes over
     iterations, optionally evaluating the frozen policy each iteration
@@ -873,14 +880,15 @@ def build_train_fn(n_phases: int, n_threads: int, eval_shape,
     carry_out, hist)`` so chunked (checkpointed) training can resume
     mid-scan.  ``faults`` perturbs both the training and the evaluation
     episodes; its key is re-derived per iteration from ``carry.it``.
+    ``kernel`` is :func:`build_episode_fn`'s.
     """
     episode = build_episode_fn(n_phases, n_threads, cycle_time,
                                demand_cache, gated, presample_noise,
-                               ddr_attribution, fused, debug_finite)
+                               ddr_attribution, fused, debug_finite, kernel)
     eval_episode = (build_episode_fn(eval_shape[0], eval_shape[1],
                                      cycle_time, demand_cache, gated,
                                      presample_noise, ddr_attribution,
-                                     fused, debug_finite)
+                                     fused, debug_finite, kernel)
                     if eval_shape is not None else None)
 
     def train_one(params, train_scheds, eval_sched, base, phase_mask, cfg,
@@ -919,6 +927,20 @@ def build_train_fn(n_phases: int, n_threads: int, eval_shape,
     return train_one
 
 
+def episode_lowering(calls: collections.Counter, n_episodes: int,
+                     fused: bool) -> bool | None:
+    """The fused step's lowering for a call that runs ``n_episodes``
+    episodes at once (:func:`repro.kernels.soc_step.ops.episode_kernel`),
+    counted in ``calls`` as ``episode_kernel`` or ``episode_scan``.
+    ``None`` for the unfused step, which has no lowering to choose."""
+    if not fused:
+        return None
+    from repro.kernels.soc_step import ops as soc_step_ops
+    kernel = soc_step_ops.episode_kernel(n_episodes)
+    calls["episode_kernel" if kernel else "episode_scan"] += 1
+    return kernel
+
+
 class VecEnv:
     """Fully-jitted batched SoC environment over one SoC + accelerator set.
 
@@ -944,7 +966,9 @@ class VecEnv:
     auto-enables it whenever the fast path it fuses is active
     (``demand_cache and presample_noise``) — results are bitwise-identical
     to the unfused step, so only benchmarks and equivalence tests pass an
-    explicit ``False``.
+    explicit ``False``.  The batched calls lower the fused step by their
+    episode count (:func:`episode_lowering`) and count the lowering each
+    took in ``calls``.
     """
 
     def __init__(self, soc: SoCConfig,
@@ -986,6 +1010,7 @@ class VecEnv:
                                  static=self.static)
         self._episode_cache: dict = {}
         self._train_cache: dict = {}
+        self.calls = collections.Counter()
 
     @classmethod
     def from_simulator(cls, sim: SoCSimulator,
@@ -1003,11 +1028,13 @@ class VecEnv:
                    debug_finite=debug_finite)
 
     # ------------------------------------------------------------ episode
-    def _episode_fn(self, n_phases: int, n_threads: int):
+    def _episode_fn(self, n_phases: int, n_threads: int,
+                    kernel: bool | None = None):
         """Build (and cache) the spec-consuming episode closure (params
-        pre-bound).  One closure per schedule geometry serves every policy
-        family — the jit cache no longer keys on a policy kind."""
-        cache_key = ("ep", n_phases, n_threads)
+        pre-bound).  One closure per schedule geometry and lowering serves
+        every policy family — the jit cache no longer keys on a policy
+        kind."""
+        cache_key = ("ep", n_phases, n_threads, kernel)
         if cache_key in self._episode_cache:
             return self._episode_cache[cache_key]
         base_fn = build_episode_fn(n_phases, n_threads,
@@ -1015,7 +1042,8 @@ class VecEnv:
                                    presample_noise=self.presample_noise,
                                    ddr_attribution=self.ddr_attribution,
                                    fused=self.fused_step,
-                                   debug_finite=self.debug_finite)
+                                   debug_finite=self.debug_finite,
+                                   kernel=kernel)
         params = self.params
 
         def episode(sched, spec, cfg, weights, key, faults=None):
@@ -1110,9 +1138,12 @@ class VecEnv:
         n = specs.learned.shape[0]
         if keys is None:
             keys = jax.vmap(jax.random.PRNGKey)(jnp.arange(n))
-        cache_key = ("specs_jit", compiled.n_phases, compiled.n_threads)
+        kernel = episode_lowering(self.calls, n, self.fused_step)
+        cache_key = ("specs_jit", compiled.n_phases, compiled.n_threads,
+                     kernel)
         if cache_key not in self._episode_cache:
-            ep = self._episode_fn(compiled.n_phases, compiled.n_threads)
+            ep = self._episode_fn(compiled.n_phases, compiled.n_threads,
+                                  kernel)
 
             def one(sched, spec, cfg_, w, key, f):
                 _, res = ep(sched, spec, cfg_, w, key, f)
@@ -1135,8 +1166,9 @@ class VecEnv:
         return res
 
     # ------------------------------------------------------------ training
-    def _train_fn(self, n_phases: int, n_threads: int, eval_shape):
-        cache_key = (n_phases, n_threads, eval_shape)
+    def _train_fn(self, n_phases: int, n_threads: int, eval_shape,
+                  kernel: bool | None = None):
+        cache_key = (n_phases, n_threads, eval_shape, kernel)
         if cache_key in self._train_cache:
             return self._train_cache[cache_key]
         base_fn = build_train_fn(n_phases, n_threads, eval_shape,
@@ -1144,7 +1176,8 @@ class VecEnv:
                                  presample_noise=self.presample_noise,
                                  ddr_attribution=self.ddr_attribution,
                                  fused=self.fused_step,
-                                 debug_finite=self.debug_finite)
+                                 debug_finite=self.debug_finite,
+                                 kernel=kernel)
         params = self.params
 
         def train_one(train_scheds, eval_sched, base, cfg, weights, carry,
@@ -1219,7 +1252,8 @@ class VecEnv:
         _, batched = self._train_fn(
             train_apps[0].n_phases, train_apps[0].n_threads,
             None if eval_app is None else
-            (eval_app.n_phases, eval_app.n_threads))
+            (eval_app.n_phases, eval_app.n_threads),
+            episode_lowering(self.calls, keys.shape[0], self.fused_step))
         q0 = qlearn.init_qstate_batch(cfg, keys.shape[0])
         qs, _, hist = batched(scheds, eval_sched, base, cfg, weights_batch,
                               self._batched_carry(keys), q0, faults)
@@ -1256,11 +1290,12 @@ class VecEnv:
         eval_sched = eval_app.schedule if eval_app is not None else None
         base = (self.baseline_episode(eval_app, faults=faults)
                 if eval_app is not None else None)
+        b = keys.shape[0]
         _, batched = self._train_fn(
             train_apps[0].n_phases, train_apps[0].n_threads,
             None if eval_app is None else
-            (eval_app.n_phases, eval_app.n_threads))
-        b = keys.shape[0]
+            (eval_app.n_phases, eval_app.n_threads),
+            episode_lowering(self.calls, b, self.fused_step))
         qs = qlearn.init_qstate_batch(cfg, b)
         carry = self._batched_carry(keys)
         hist_t = jnp.zeros((b, iters), jnp.float32)
@@ -1303,10 +1338,12 @@ class VecEnv:
         (itself run under the same ``faults``, so the ratios isolate the
         policy's contribution from the storm's)."""
         base = self.baseline_episode(compiled, faults=faults)
-        cache_key = ("batched_eval", compiled.n_phases, compiled.n_threads)
+        kernel = episode_lowering(self.calls, keys.shape[0], self.fused_step)
+        cache_key = ("batched_eval", compiled.n_phases, compiled.n_threads,
+                     kernel)
         if cache_key not in self._train_cache:
             episode = self._episode_fn(compiled.n_phases,
-                                       compiled.n_threads)
+                                       compiled.n_threads, kernel)
             # rewards don't steer a frozen agent; any weights do
             w = rewards.PAPER_DEFAULT_WEIGHTS
 
