@@ -74,13 +74,15 @@ class QState(NamedTuple):
     frozen: jnp.ndarray   # () bool — True once training is disabled
 
 
-def init_qstate(cfg: QConfig = QConfig()) -> QState:
+def init_qstate(cfg: QConfig = QConfig(), batch: tuple = ()) -> QState:
+    """A fresh agent; ``batch`` leads every leaf with that many agent axes
+    (built at full shape, with no ``vmap``)."""
+    table = tuple(batch) + (cfg.n_states, cfg.n_actions)
     return QState(
-        qtable=jnp.full((cfg.n_states, cfg.n_actions), cfg.q_init,
-                        jnp.float32),
-        visits=jnp.zeros((cfg.n_states, cfg.n_actions), jnp.int32),
-        step=jnp.zeros((), jnp.int32),
-        frozen=jnp.zeros((), bool),
+        qtable=jnp.full(table, cfg.q_init, jnp.float32),
+        visits=jnp.zeros(table, jnp.int32),
+        step=jnp.zeros(batch, jnp.int32),
+        frozen=jnp.zeros(batch, bool),
     )
 
 
@@ -332,11 +334,6 @@ def episode_step_presampled(
     reward, aux = reward_fn(action)
     new_qs = update(qs, cfg, state_idx, action, reward)
     return new_qs, (action, reward, aux)
-
-
-def init_qstate_batch(cfg: QConfig, batch: int) -> QState:
-    """``batch`` independent agents as one stacked QState pytree (vmap axis 0)."""
-    return jax.vmap(lambda _: init_qstate(cfg))(jnp.arange(batch))
 
 
 def freeze(qs: QState) -> QState:

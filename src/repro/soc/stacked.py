@@ -335,7 +335,10 @@ class StackedVecEnv:
     directly from configs.  All public entry points run every lane in a
     single jitted call; each one's per-call host preparation runs under a
     ``cohm.prep`` profiler span, and its jit cache lookup and call under
-    ``cohm.launch``.
+    ``cohm.launch``.  Training prepares no arrays on the host: its
+    program stacks the schedules and builds the fresh agents and carry,
+    so its ``cohm.prep`` is Python bookkeeping, plus the baseline episode
+    when it evaluates each iteration.
 
     ``fused_step`` follows :class:`~repro.soc.vecenv.VecEnv`: ``None``
     (default) enables the :mod:`repro.kernels.soc_step` episode lowering —
@@ -599,15 +602,18 @@ class StackedVecEnv:
         (K,) array for per-lane decay horizons (lanes differ in
         invocations per iteration).  Returns a QState with (K, B, ...)
         leaves and, when ``eval_stacked`` is given, per-iteration
-        (norm_time, norm_mem) histories of shape (K, B, iterations)."""
+        (norm_time, norm_mem) histories of shape (K, B, iterations).
+
+        The program itself stacks the iterations' schedules and builds
+        the fresh (K, B) agents and their ``TrainCarry`` from ``keys``,
+        so ``cohm.prep`` holds only Python bookkeeping (the lowering, the
+        config's axes, the cache key) and, with ``eval_stacked``, the
+        baseline episode call that normalizes the histories."""
         self.calls["train"] += 1
         first = stacked_iters[0]
         eval_shape = (None if eval_stacked is None
                       else (eval_stacked.n_phases, eval_stacked.n_threads))
         with jax.profiler.TraceAnnotation("cohm.prep"):
-            scheds = jax.tree_util.tree_map(
-                lambda *xs: jnp.stack(xs, axis=1),
-                *[st.schedule for st in stacked_iters])
             if eval_stacked is not None:
                 eval_sched = eval_stacked.schedule
                 base = self.baseline(eval_stacked, faults=faults)
@@ -616,18 +622,10 @@ class StackedVecEnv:
             else:
                 eval_sched = base = pmask = None
                 eval_axes = (None, None, None)
-
-            B = keys.shape[1]
-            kernel = vec.episode_lowering(self.calls, self.n_lanes * B,
+            kernel = vec.episode_lowering(self.calls,
+                                          self.n_lanes * keys.shape[1],
                                           self.fused_step)
-            q0 = jax.tree_util.tree_map(
-                lambda x: jnp.broadcast_to(x, (self.n_lanes,) + x.shape),
-                qlearn.init_qstate_batch(qlearn.QConfig(), B))
             axes = _cfg_axes(cfg)
-            carry_axes = vec.TrainCarry(key=0, it=None, best=0)
-            carry0 = vec.TrainCarry(
-                key=jnp.asarray(keys), it=jnp.zeros((), jnp.int32),
-                best=jnp.full(keys.shape[:2], -jnp.inf, jnp.float32))
         with jax.profiler.TraceAnnotation("cohm.launch"):
             cache_key = ("train_jit", first.n_phases, first.n_threads,
                          eval_shape, tuple(axes), kernel)
@@ -638,18 +636,33 @@ class StackedVecEnv:
                     fused=self.fused_step, kernel=kernel)
                 # Carry batches (key, best) per agent / per lane; the
                 # iteration counter and the FaultSpec replicate everywhere.
-                self._cache[cache_key] = jax.jit(_vmap_grid(
+                carry_axes = vec.TrainCarry(key=0, it=None, best=0)
+                grid = _vmap_grid(
                     train_one,
                     lane_axes=(0, 0, *eval_axes, axes, None, carry_axes, 0,
                                None),
                     item_axes=(None, None, None, None, None, None,
                                rewards.RewardWeights(0, 0, 0), carry_axes,
                                0, None),
-                    out_axes=(0, carry_axes, 0)))
-            qs, _, hist = self._cache[cache_key](
-                self.params, scheds, eval_sched, base, pmask, cfg,
-                weights_batch, carry0, q0, faults)
-        return qs, hist
+                    out_axes=(0, carry_axes, 0))
+
+                def prog(params, iter_scheds, eval_sched, base, pmask, cfg_,
+                         weights, keys, f):
+                    scheds = jax.tree_util.tree_map(
+                        lambda *xs: jnp.stack(xs, axis=1), *iter_scheds)
+                    # QConfig()'s table: cfg_'s fields are traced here.
+                    q0 = qlearn.init_qstate(qlearn.QConfig(), keys.shape[:2])
+                    carry0 = vec.TrainCarry(
+                        key=keys, it=jnp.zeros((), jnp.int32),
+                        best=jnp.full(keys.shape[:2], -jnp.inf, jnp.float32))
+                    qs, _, hist = grid(params, scheds, eval_sched, base,
+                                       pmask, cfg_, weights, carry0, q0, f)
+                    return qs, hist
+
+                self._cache[cache_key] = jax.jit(prog)
+            return self._cache[cache_key](
+                self.params, tuple(st.schedule for st in stacked_iters),
+                eval_sched, base, pmask, cfg, weights_batch, keys, faults)
 
     def evaluate_batched(self, stacked: StackedApps, qstates: qlearn.QState,
                          cfg: qlearn.QConfig, keys=None, faults=None

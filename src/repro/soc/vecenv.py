@@ -1254,7 +1254,7 @@ class VecEnv:
             None if eval_app is None else
             (eval_app.n_phases, eval_app.n_threads),
             episode_lowering(self.calls, keys.shape[0], self.fused_step))
-        q0 = qlearn.init_qstate_batch(cfg, keys.shape[0])
+        q0 = qlearn.init_qstate(cfg, keys.shape[:1])
         qs, _, hist = batched(scheds, eval_sched, base, cfg, weights_batch,
                               self._batched_carry(keys), q0, faults)
         return qs, hist
@@ -1296,7 +1296,7 @@ class VecEnv:
             None if eval_app is None else
             (eval_app.n_phases, eval_app.n_threads),
             episode_lowering(self.calls, b, self.fused_step))
-        qs = qlearn.init_qstate_batch(cfg, b)
+        qs = qlearn.init_qstate(cfg, (b,))
         carry = self._batched_carry(keys)
         hist_t = jnp.zeros((b, iters), jnp.float32)
         hist_m = jnp.zeros((b, iters), jnp.float32)
